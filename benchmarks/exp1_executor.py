@@ -23,10 +23,10 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.core import (PilotDescription, RPEXExecutor, ResourceSpec,
                         TaskState, translate)
-from repro.compat import shard_map
 
 
 def _noop_spmd(mesh, x):

@@ -15,11 +15,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (DataFlowKernel, PilotDescription, RPEXExecutor,
                         python_app, spmd_app)
-from repro.compat import shard_map
 
 TRUE_OPT = 1.7
 

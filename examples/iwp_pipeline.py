@@ -16,11 +16,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (DataFlowKernel, PilotDescription, RPEXExecutor,
                         python_app, spmd_app)
-from repro.compat import shard_map
 
 TILE = 90          # reduced 360 -> 90 for the CPU container
 TILES_PER_IMG = 8

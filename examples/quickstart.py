@@ -9,11 +9,11 @@ futures, and runs them under the RPEX executor — the paper's full stack
 """
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (DataFlowKernel, PilotDescription, RPEXExecutor,
                         bash_app, python_app, spmd_app)
-from repro.compat import shard_map
 
 
 @python_app

@@ -70,8 +70,8 @@ class ThreadPoolExecutor(Executor):
             try:
                 if task.kind == "spmd":
                     import jax
-                    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                                         devices=jax.devices()[:1])
+                    from ..sharding.partition import make_mesh
+                    mesh = make_mesh((1, 1), devices=jax.devices()[:1])
                     res = task.fn(mesh, *task.args, **task.kwargs)
                 else:
                     res = task.fn(*task.args, **task.kwargs)

@@ -93,7 +93,9 @@ class PilotDescription:
                                       # run off the GIL; spmd stays local)
     worker_idle_s: float = 30.0       # pool threads idle longer than this
                                       # reap themselves (bounded pool)
-    proc_start_method: Optional[str] = None  # "fork" (default) | "spawn"
+    proc_start_method: Optional[str] = None  # "fork" (default) | "spawn";
+                                      # off the CPU always spawn, held to
+                                      # the CPU (the chip is this process's)
     shm_threshold: Optional[int] = 256 * 1024
                                       # proc transport: ndarray args/results
                                       # at/above this size cross the worker
@@ -124,7 +126,8 @@ class Pilot:
                                desc.transport, desc.max_workers,
                                idle_s=desc.worker_idle_s,
                                start_method=desc.proc_start_method,
-                               shm_threshold=desc.shm_threshold)).start()
+                               shm_threshold=desc.shm_threshold,
+                               device_platform=devices[0].platform)).start()
         self.objectstore = None   # pool-wired data plane (docs/dataplane.md)
         self.t_start = time.monotonic()
         self.draining = False     # a draining pilot accepts no new work

@@ -14,9 +14,10 @@ subsequent task with the same signature is a cheap cached call.  The
 ``cache=False`` mode exists only for the Exp-1 ablation that reproduces the
 paper's cold-communicator overhead.
 
-On the CPU container, slots may outnumber real devices: slot blocks then
-map onto the available devices (dedup'd), preserving scheduling semantics
-while executing on what exists — the same code drives a real pod.
+On the CPU, slots may outnumber real devices: slot blocks then map onto
+the available devices (dedup'd), preserving scheduling semantics while
+executing on what exists.  On an accelerator a slot is a chip, and a slot
+id beyond the device count is an error, never a silent time-share.
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
+from ..sharding.partition import make_mesh
 from .futures import ResourceSpec, TaskRecord
 
 
@@ -43,6 +45,10 @@ class SPMDFunctionExecutor:
                 mesh_shape: Optional[Tuple[int, int]] = None):
         """Carve the sub-mesh ('Intra-communicator') for a slot block."""
         nreal = len(self.devices)
+        if self.devices[0].platform != "cpu" and max(slot_ids) >= nreal:
+            raise ValueError(
+                f"slots {tuple(slot_ids)} exceed the {nreal} "
+                f"{self.devices[0].platform} devices of this pilot")
         devs = []
         seen = set()
         for s in slot_ids:
@@ -59,8 +65,7 @@ class SPMDFunctionExecutor:
         with self._lock:
             if self.cache_enabled and key in self._mesh_cache:
                 return self._mesh_cache[key]
-        mesh = jax.make_mesh(shape, ("data", "model"),
-                             devices=devs[: shape[0] * shape[1]])
+        mesh = make_mesh(shape, devices=devs[: shape[0] * shape[1]])
         with self._lock:
             if self.cache_enabled:
                 self._mesh_cache[key] = mesh

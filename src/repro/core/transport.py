@@ -425,15 +425,14 @@ class ProcessTransport(_PoolBase):
 
     def __init__(self, max_workers: int = 32, idle_s: float = 30.0,
                  start_method: Optional[str] = None,
-                 shm_threshold: Optional[int] = None):
+                 shm_threshold: Optional[int] = None,
+                 device_platform: str = "cpu"):
         super().__init__(max_workers, idle_s)
         self.shm_threshold = shm_threshold   # ndarray args/results at or
                                              # above this cross via shared
                                              # memory; None = pickle pipe
-        # fork is the cheap default on linux (the child never touches the
-        # parent's XLA runtime: the serializer host-transfers jax leaves
-        # before they cross); spawn is the conservative opt-in
-        self._mp = multiprocessing.get_context(start_method or "fork")
+        self._mp, self._worker_platform = _worker_context(start_method,
+                                                          device_platform)
         self._seg_cache = _SegCache()   # park-once for frozen (published)
                                         # argument arrays
         self._pcond = threading.Condition()
@@ -626,7 +625,8 @@ class ProcessTransport(_PoolBase):
 
     def _spawn(self) -> _ProcWorker:
         parent, child = self._mp.Pipe(duplex=True)
-        p = self._mp.Process(target=_proc_worker_main, args=(child,),
+        p = self._mp.Process(target=_proc_worker_main,
+                             args=(child, self._worker_platform),
                              daemon=True)
         with warnings.catch_warnings():
             # jax warns on os.fork() in its multithreaded parent; the
@@ -685,6 +685,20 @@ class ProcessTransport(_PoolBase):
         self._seg_cache.close()
 
 
+def _worker_context(start_method: Optional[str], device_platform: str):
+    """(multiprocessing context, JAX platform the worker is held to).
+
+    ``device_platform`` is the platform of the owning pilot's devices.  A
+    chip belongs to one process.  On the CPU, fork is the cheap default
+    (the child never touches the parent's XLA runtime: the serializer
+    host-transfers jax leaves before they cross).  On an accelerator, a
+    forked child would inherit the parent's device client, so workers are
+    spawned and held to the CPU instead."""
+    if device_platform == "cpu":
+        return multiprocessing.get_context(start_method or "fork"), None
+    return multiprocessing.get_context("spawn"), "cpu"
+
+
 # ----------------------------- child side -------------------------------- #
 class _RemoteCheckpoint:
     """Child-side Checkpoint proxy: same interface the body sees inproc
@@ -725,8 +739,12 @@ class _RemoteCheckpoint:
         return self._preempt
 
 
-def _proc_worker_main(conn):
+def _proc_worker_main(conn, platform: Optional[str] = None):
     """Worker-process entry: one run at a time, reused across tasks."""
+    if platform:                        # before any body can start JAX's
+        import jax                      # backends (bash bodies inherit
+        os.environ["JAX_PLATFORMS"] = platform   # the env)
+        jax.config.update("jax_platforms", platform)
     while True:
         try:
             msg = conn.recv()
@@ -817,12 +835,15 @@ TRANSPORTS = ("inproc", "proc")
 def make_transport(name: Optional[str], max_workers: int = 32,
                    idle_s: float = 30.0,
                    start_method: Optional[str] = None,
-                   shm_threshold: Optional[int] = None):
-    """Build a transport from a PilotDescription's knobs."""
+                   shm_threshold: Optional[int] = None,
+                   device_platform: str = "cpu"):
+    """Build a transport from a PilotDescription's knobs and the platform
+    of the pilot's devices."""
     if name in (None, "inproc"):
         return InprocTransport(max_workers, idle_s)
     if name == "proc":
         return ProcessTransport(max_workers, idle_s, start_method,
-                                shm_threshold=shm_threshold)
+                                shm_threshold=shm_threshold,
+                                device_platform=device_platform)
     raise ValueError(
         f"unknown transport {name!r}; expected one of {TRANSPORTS}")

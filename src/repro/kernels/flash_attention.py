@@ -85,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         attn_softcap: float = 0.0, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool):
     """q: (B, Sq, Hq, D); k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D)."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]

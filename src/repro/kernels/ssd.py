@@ -24,41 +24,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
+def _kernel(x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref,
             y_ref, st_ref, dall_ref, dchunk_ref, *, Q):
-    x = x_ref[0, 0, 0].astype(jnp.float32)     # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (Q,)
-    A = a_ref[0].astype(jnp.float32)           # ()
-    Bc = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
-    Cc = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
+    x = x_ref[0, 0, 0].astype(jnp.float32)       # (Q, P)
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)  # (1, Q)
+    dt_col = dtc_ref[0, 0, 0].astype(jnp.float32)  # (Q, 1)
+    A = a_ref[0].astype(jnp.float32)             # (1, 1)
+    Bc = b_ref[0, 0].astype(jnp.float32)         # (Q, N)
+    Cc = c_ref[0, 0].astype(jnp.float32)         # (Q, N)
 
-    la = dt * A                                 # (Q,) log-decay
-    cum = jnp.cumsum(la)                        # L_i inclusive
-    diff = cum[:, None] - cum[None, :]          # (Qi, Qj)
+    # inclusive cumulative log-decay L, as a column (L_i) and a row (L_j):
+    # masked reductions, since Mosaic has no cumsum
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(iota_j <= iota_i, jnp.exp(diff), 0.0)
+    causal = iota_j <= iota_i
+    cum_col = jnp.sum(jnp.where(causal, dt_row * A, 0.0), axis=1,
+                      keepdims=True)                            # (Q, 1)
+    cum_row = jnp.sum(jnp.where(iota_i <= iota_j, dt_col * A, 0.0), axis=0,
+                      keepdims=True)                            # (1, Q)
+    total = jnp.sum(dt_row * A, axis=1, keepdims=True)          # (1, 1)
+    L = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)      # (Qi, Qj)
     cb = jax.lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Qi,Qj)
-    M = cb * L * dt[None, :]
+    M = cb * L * dt_row
     y = jax.lax.dot_general(M, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (Q,P)
-    decay_to_end = jnp.exp(cum[-1] - cum)       # (Q,)
-    wB = Bc * (decay_to_end * dt)[:, None]      # (Q,N)
+    wB = Bc * (jnp.exp(total - cum_col) * dt_col)                 # (Q,N)
     state = jax.lax.dot_general(x, wB, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (P,N)
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
     st_ref[0, 0, 0] = state.astype(st_ref.dtype)
-    dall_ref[0, 0, 0] = jnp.exp(cum).astype(dall_ref.dtype)
-    dchunk_ref[0, 0, 0] = jnp.exp(cum[-1]).astype(dchunk_ref.dtype)
+    dall_ref[0, 0, 0] = jnp.exp(cum_row).astype(dall_ref.dtype)
+    dchunk_ref[0, 0, 0] = jnp.exp(total).astype(dchunk_ref.dtype)
 
 
-def ssd_chunk_kernel(x, dt, A, B_, C_, *, chunk: int, interpret: bool = True):
+def ssd_chunk_kernel(x, dt, A, B_, C_, *, chunk: int, interpret: bool):
     """Intra-chunk terms for all chunks.
 
     x: (B,S,H,P); dt: (B,S,H) f32; A: (H,); B_/C_: (B,S,N).
     Returns y_intra (B,S,H,P) f32, states (B,H,nc,P,N) f32,
     decay_all (B,H,nc,Q) f32, decay_chunk (B,H,nc) f32.
+
+    Every block's last two dims are either the array's own or (8, 128)
+    multiples, as the TPU requires: per-chunk vectors travel as (1, Q) rows
+    or (Q, 1) columns, and per-head scalars as (1, 1) tiles.
     """
     Bsz, S, H, P = x.shape
     N = B_.shape[-1]
@@ -70,36 +79,33 @@ def ssd_chunk_kernel(x, dt, A, B_, C_, *, chunk: int, interpret: bool = True):
     dtr = dt.reshape(Bsz, nc, Q, H).transpose(0, 3, 1, 2)
     Br = B_.reshape(Bsz, nc, Q, N)
     Cr = C_.reshape(Bsz, nc, Q, N)
+    tile = lambda b, h, c: (b, h, c, 0, 0)  # noqa: E731
 
     y, st, dall, dchunk = pl.pallas_call(
         functools.partial(_kernel, Q=Q),
         grid=(Bsz, H, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, Q, P), tile),
+            pl.BlockSpec((1, 1, 1, 1, Q), tile),
+            pl.BlockSpec((1, 1, 1, Q, 1), tile),
+            pl.BlockSpec((1, 1, 1), lambda b, h, c: (h, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, P, N), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, 1, Q, P), tile),
+            pl.BlockSpec((1, 1, 1, P, N), tile),
+            pl.BlockSpec((1, 1, 1, 1, Q), tile),
+            pl.BlockSpec((1, 1, 1, 1, 1), tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bsz, H, nc, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((Bsz, H, nc, P, N), jnp.float32),
-            jax.ShapeDtypeStruct((Bsz, H, nc, Q), jnp.float32),
-            jax.ShapeDtypeStruct((Bsz, H, nc), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, H, nc, 1, Q), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, H, nc, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(xr, dtr, A, Br, Cr)
+    )(xr, dtr[:, :, :, None, :], dtr[..., None], A.reshape(H, 1, 1), Br, Cr)
     # y back to (B,S,H,P)
     y = y.transpose(0, 2, 3, 1, 4).reshape(Bsz, S, H, P)
-    return y, st, dall, dchunk
-
-
-def _kernel_ref_note():
-    """The (1,1,...) leading block dims exist because pallas interpret mode
-    requires block shapes to cover every array dim; squeezed in-kernel."""
+    return y, st, dall[:, :, :, 0], dchunk[:, :, :, 0, 0]

@@ -30,15 +30,21 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES, get_config, get_shape, cells
-from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.models import transformer as T
 from repro.optim import AdamW
 from repro.roofline.analysis import roofline_terms
 from repro.roofline.hlo_cost import analyze as hlo_analyze
-from repro.sharding.partition import PartitionRules, ShardCtx
+from repro.sharding.partition import PartitionRules, ShardCtx, make_mesh
 
 ART_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts" / "dryrun"
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    """16x16 = 256 chips per pod; multi_pod adds a leading pod axis (2 pods)."""
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16))
 
 
 def _opt_for(cfg) -> AdamW:
@@ -122,7 +128,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     out_path.parent.mkdir(parents=True, exist_ok=True)
     t0 = time.time()
     if mesh_shape:
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(mesh_shape)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     cfg, shape, fn, avals, in_sh, out_sh = build_cell(

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs import get_config, reduce_config
 from repro.core import PilotDescription, RPEXExecutor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import transformer as T
 from repro.sharding.partition import NULL_CTX
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -2,13 +2,18 @@
 
 The training job is expressed as an RPEX workflow (the paper's model): the
 device pilot runs `train_segment` SPMD tasks (N optimizer steps each), while
-single-slot Python tasks handle evaluation and checkpoint commits
-concurrently — the heterogeneous-task mix of the Colmena use case, applied
-to an LM pre-training job.
+single-slot Python tasks on a host pilot handle evaluation and checkpoint
+commits concurrently — the heterogeneous-task mix of the Colmena use case,
+applied to an LM pre-training job.
+
+The device pilot holds one slot per device (``--slots`` may add spares); a
+segment takes as many slots as its mesh spans (one without a mesh).  The
+host pilot's slots are its own, so a commit never holds up the next segment.
 
 Fault tolerance: auto-resume from the newest checkpoint (params, optimizer
-state, data cursor); ``--inject-failure`` kills a slot block mid-run to
-exercise retry + reschedule.
+state, data cursor; ``--no-resume`` starts over); ``--inject-failure``
+kills device slots mid-run to exercise reschedule onto spare slots, and is
+refused when no slot block for a segment would be left.
 
 Example (CPU, reduced config):
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
@@ -28,12 +33,13 @@ import numpy as np
 from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get_config, reduce_config
 from repro.core import (DataFlowKernel, PilotDescription, RPEXExecutor,
-                        python_app, spmd_app)
+                        SlotScheduler, python_app, spmd_app)
 from repro.data.pipeline import DataConfig, ShardedLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import transformer as T
 from repro.optim import AdamW, cosine_schedule
-from repro.sharding.partition import PartitionRules, ShardCtx
+from repro.sharding.partition import PartitionRules, ShardCtx, make_mesh
 
 
 def build_state(cfg, mesh, rules, seed=0):
@@ -64,27 +70,50 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--eval-every", type=int, default=50)
-    ap.add_argument("--slots", type=int, default=0)
+    ap.add_argument("--slots", type=int, default=0,
+                    help="device pilot slots (0 = one per mesh device)")
     ap.add_argument("--data-shards", type=int, default=1)
     ap.add_argument("--model-shards", type=int, default=1)
     ap.add_argument("--inject-failure", type=int, default=0,
-                    help="kill this many slots mid-run (fault drill)")
-    ap.add_argument("--resume", action="store_true", default=True)
+                    help="kill this many device slots mid-run (fault "
+                         "drill; needs spare --slots)")
+    ap.add_argument("--resume", action=argparse.BooleanOptionalAction,
+                    default=True)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
     rules = PartitionRules()
 
-    rpex = RPEXExecutor(PilotDescription(
-        n_slots=args.slots or max(4, len(jax.devices()))))
-    n_dev = len(jax.devices())
-    use_mesh = args.data_shards * args.model_shards <= n_dev and \
-        args.data_shards * args.model_shards > 1
-    mesh = (jax.make_mesh((args.data_shards, args.model_shards),
-                          ("data", "model")) if use_mesh else None)
+    n_shards = args.data_shards * args.model_shards
+    n_slots = args.slots or n_shards
+    devices = jax.devices()[:max(n_slots, n_shards)]
+    if len(devices) < n_shards:
+        raise ValueError(f"{args.data_shards}x{args.model_shards} shards "
+                         f"need {n_shards} devices; {len(devices)} visible")
+    if devices[0].platform != "cpu" and len(devices) < n_slots:
+        raise ValueError(f"--slots {n_slots}: a slot is a device, and "
+                         f"{len(devices)} are visible")
+    if args.inject_failure:
+        probe = SlotScheduler(n_slots)
+        probe.mark_failed(range(args.inject_failure))
+        if probe.allocate("segment", n_shards) is None:
+            raise ValueError(
+                f"--inject-failure {args.inject_failure} leaves no block of "
+                f"{n_shards} of the {n_slots} device slots for a segment; "
+                f"raise --slots")
+    # device pilot first (rpex.pilot): the segments' slots, one per device;
+    # the host pilot runs the eval and checkpoint helpers beside them
+    rpex = RPEXExecutor([
+        PilotDescription(n_slots=n_slots, devices=devices, kinds=("spmd",),
+                         name="device"),
+        PilotDescription(n_slots=2, devices=devices[:1], kinds=("python",),
+                         name="host")])
+    mesh = (make_mesh((args.data_shards, args.model_shards),
+                      devices=devices[:n_shards]) if n_shards > 1 else None)
     sctx = ShardCtx(mesh, rules)
 
     params, opt, opt_state = build_state(cfg, mesh, rules)
@@ -109,22 +138,21 @@ def main(argv=None):
                                 microbatches=args.microbatches)
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
 
-    n_slots = rpex.pilot.n_slots
-    seg_slots = max(1, n_slots - 2)      # leave slots for eval/ckpt helpers
+    eval_loss = jax.jit(lambda p, b: M.loss_fn(cfg, p, b, sctx)[0])
 
-    @spmd_app(slots=seg_slots, jit=False)
+    @spmd_app(slots=n_shards, jit=False)
     def train_segment(task_mesh, params, opt_state, batches):
         # segment body drives the pre-jitted step; task_mesh is the carved
         # sub-mesh (the actual sharded mesh is managed by jit_step's specs)
         metrics = None
         for b in batches:
             params, opt_state, metrics = jit_step(params, opt_state, b)
-        return params, opt_state, metrics
+        return (params, opt_state, metrics,
+                tuple(d.id for d in task_mesh.devices.flat))
 
     @python_app
     def evaluate(params, batch):
-        loss, _ = M.loss_fn(cfg, params, batch, sctx)
-        return float(loss)
+        return float(eval_loss(params, batch))
 
     @python_app
     def commit_checkpoint(step, params, opt_state, cursor):
@@ -136,18 +164,23 @@ def main(argv=None):
     with DataFlowKernel(executors={"rpex": rpex}, run_id=None) as dfk:
         step = start_step
         pending = []
+        evals = []
         failed_injected = False
         while step < args.steps:
             n = min(args.segment, args.steps - step)
             batches = [jax.tree.map(jnp.asarray, next(loader))
                        for _ in range(n)]
+            t_seg = time.time()
             fut = train_segment(params, opt_state, batches)
-            params, opt_state, metrics = fut.result()
+            params, opt_state, metrics, seg_devices = fut.result()
             step += n
             loss = float(metrics["loss"])
             losses.append(loss)
-            print(f"[train] step {step:5d} loss {loss:.4f} "
-                  f"({(time.time()-t0):.1f}s)", flush=True)
+            seg_s = time.time() - t_seg
+            print(f"[train] step {step:5d} loss {loss:.4f} segment "
+                  f"{seg_s:.2f}s ({seg_s / n:.3f}s/step) on devices "
+                  f"{list(seg_devices)} ({(time.time()-t0):.1f}s)",
+                  flush=True)
             if args.inject_failure and not failed_injected and \
                     step >= args.steps // 2:
                 failed_injected = True
@@ -165,10 +198,13 @@ def main(argv=None):
                                                  loader.cursor))
             if step % args.eval_every == 0:
                 eb = jax.tree.map(jnp.asarray, next(loader))
-                pending.append(evaluate(snap_p, eb))
+                evals.append((step, evaluate(snap_p, eb)))
         for f in pending:
             f.result()
+        for at, f in evals:
+            print(f"[train] eval at step {at}: loss {f.result():.4f}")
     loader.close()
+    print(f"[train] executor stats {rpex.pilot.executor.stats}")
     rpex.shutdown()
     if losses:
         print(f"[train] done: {step} steps, final loss {losses[-1]:.4f}, "

@@ -14,10 +14,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import apply_rope, softcap
-from ..compat import shard_map
 
 NEG_INF = -1e30
 

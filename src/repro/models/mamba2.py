@@ -16,7 +16,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
+from jax import shard_map
 
 
 def mamba_params_spec(cfg):

@@ -14,7 +14,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 # logical axis -> ordered candidate mesh axes (first divisible wins; the
@@ -43,6 +43,16 @@ DEFAULT_RULES: Dict[str, Tuple[Tuple[str, ...], ...]] = {
     "state":    ((),),
     None:       ((),),
 }
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str] = ("data", "model"),
+              devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``: the compiler propagates
+    shardings, steered by ``ShardCtx.act``'s constraints (which JAX accepts
+    only on ``Auto`` axes; ``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 class PartitionRules:

@@ -92,7 +92,7 @@ def test_failure_propagates_downstream(rpex):
 def test_spmd_submesh_collective(rpex):
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     @spmd_app(slots=4)
     def psum_task(mesh, x):

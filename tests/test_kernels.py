@@ -4,12 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                      # fall back to the vendored shim
-    from _propshim import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.kernels import ops
 from repro.kernels.ref import (attention_reference, ssd_reference,

@@ -1,11 +1,7 @@
 """Property tests for the device-slot scheduler (RP Agent analog)."""
+import hypothesis.strategies as st
 import pytest
-
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                      # fall back to the vendored shim
-    from _propshim import given, settings, st
+from hypothesis import given, settings
 
 from repro.core.scheduler import SlotScheduler, _align_of
 

@@ -6,19 +6,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    import hypothesis.strategies as st
-    from hypothesis import given, settings
-except ImportError:                      # fall back to the vendored shim
-    from _propshim import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import DataConfig, ShardedLoader, SyntheticCorpus
 from repro.models.moe import _positions, moe_ffn, moe_params_spec
 from repro.optim import AdamW, cosine_schedule
-from repro.sharding.partition import NULL_CTX, PartitionRules
+from repro.sharding.partition import NULL_CTX, PartitionRules, make_mesh
 
 
 # ------------------------------- optimizer ------------------------------ #
@@ -165,7 +161,7 @@ def test_partition_fallbacks():
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
     # synthetic 2D mesh shape check via spec_for on an abstract mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     r = PartitionRules()
     # degenerate mesh: everything falls back to replicated
     assert r.spec_for(("vocab", "embed_w"), (1000, 64), mesh) == \

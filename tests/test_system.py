@@ -32,6 +32,23 @@ def test_train_driver_end_to_end(tmp_path):
     assert len(losses2) == 2                 # only steps 20->30 ran
 
 
+def test_compile_cache_dir(monkeypatch):
+    """An entry point keeps JAX's cache where JAX_COMPILATION_CACHE_DIR
+    says, and otherwise at the fixed <repo>/.jax_cache."""
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
+        assert enable_compile_cache() == "cache-from-env"
+        assert jax.config.jax_compilation_cache_dir is None   # left alone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(REPO / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_serve_driver_end_to_end():
     from repro.launch.serve import main
     outputs = main(["--arch", "smollm-360m", "--reduced", "--requests", "6",
@@ -87,11 +104,11 @@ from repro.configs import get_config, reduce_config
 from repro.models import model as M
 from repro.models import transformer as T
 from repro.optim import AdamW
-from repro.sharding.partition import PartitionRules, ShardCtx
+from repro.sharding.partition import PartitionRules, ShardCtx, make_mesh
 
 # sharded-vs-local train step parity on a reduced MoE config
 cfg = reduce_config(get_config("qwen3-moe-235b-a22b"), num_layers=2)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4))
 rules = PartitionRules()
 params = T.init_params(cfg, jax.random.PRNGKey(0))
 B, S = 4, 16

@@ -312,6 +312,34 @@ def test_proc_mode_spmd_stays_inproc():
 
 
 @pytest.mark.timeout(120)
+def test_proc_workers_held_to_cpu_when_parent_owns_accelerator(monkeypatch):
+    """A chip belongs to one process: when the pilot's devices are an
+    accelerator, workers are spawned (no inherited device client) and
+    their JAX is held to the CPU.  The device platform is steered here;
+    a pilot passes its own devices' platform."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)   # not inherited
+    on_cpu = ProcessTransport(max_workers=1)
+    assert on_cpu._worker_platform is None
+    on_cpu.shutdown()
+    tr =ProcessTransport(max_workers=1, device_platform="tpu")
+    try:
+        assert tr._mp.get_start_method() == "spawn"
+        assert tr._worker_platform == "cpu"
+        out = tr.execute(translate(_worker_jax_env, (), {}))
+        assert out == ("cpu", "cpu", "cpu")
+    finally:
+        tr.shutdown()
+
+
+def _worker_jax_env():
+    import os
+
+    import jax
+    return (os.environ.get("JAX_PLATFORMS"), jax.config.jax_platforms,
+            jax.default_backend())
+
+
+@pytest.mark.timeout(120)
 def test_proc_mode_unserializable_body_falls_back_inproc():
     """A body the serializer cannot ship (closure over a live lock that
     it *uses*) degrades to in-process execution instead of failing."""
