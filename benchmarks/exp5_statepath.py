@@ -121,7 +121,6 @@ class BaselineDFK(DataFlowKernel):
                           args=args, kwargs=kwargs,
                           resources=resources or ResourceSpec())
         future = AppFuture(node)
-        self.tasks[node.uid] = node
         label = (executor or getattr(fn, "__executor__", None)
                  or self.default_executor)
         ex = self.executors[label]

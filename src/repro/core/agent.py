@@ -70,6 +70,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+from . import tracing
 from .checkpoint import Checkpoint, CheckpointStore, TaskPreempted
 from .faults import PilotLost, SlotFailure
 from .futures import (TERMINAL, ResourceSpec, TaskRecord, TaskState,
@@ -219,14 +220,15 @@ class Agent:
             # hot submit->run path; priority order is vacuous on an empty
             # queue, so semantics are unchanged)
             if not self._wait:
-                slots = self.scheduler.allocate(task.uid,
-                                                task.resources.slots)
-                if slots is not None:
-                    task.slot_ids = slots
-                    task.transition(TaskState.SCHEDULED, self.store)
-                    self._running[task.uid] = task
-                    self._dispatch(task)
-                    return True
+                with tracing.span("rp.agent.schedule", task.uid):
+                    slots = self.scheduler.allocate(task.uid,
+                                                    task.resources.slots)
+                    if slots is not None:
+                        task.slot_ids = slots
+                        task.transition(TaskState.SCHEDULED, self.store)
+                        self._running[task.uid] = task
+                        self._dispatch(task)
+                        return True
             heapq.heappush(self._wait,
                            (-task.resources.priority, self._seq, task))
             self._seq += 1
@@ -255,21 +257,26 @@ class Agent:
             if not self._wait:
                 pending.sort(key=lambda t: -t.resources.priority)  # stable
                 cut = None
-                for i, t in enumerate(pending):
-                    slots = self.scheduler.allocate(t.uid, t.resources.slots)
-                    if slots is None:
-                        cut = i
-                        break
-                    if done_cb is not None:
-                        self._done_cb[t.uid] = done_cb
-                    self._outstanding += 1
-                    self._demand_slots += t.resources.slots
-                    self._kadd(self._kind_demand, model_kind(t),
-                               t.resources.slots)
-                    t.slot_ids = slots
-                    t.transition(TaskState.SCHEDULED, self.store)
-                    self._running[t.uid] = t
-                    self._dispatch(t)
+                with tracing.span("rp.agent.schedule") as sp:
+                    for i, t in enumerate(pending):
+                        slots = self.scheduler.allocate(t.uid,
+                                                        t.resources.slots)
+                        if slots is None:
+                            cut = i
+                            break
+                        if done_cb is not None:
+                            self._done_cb[t.uid] = done_cb
+                        self._outstanding += 1
+                        self._demand_slots += t.resources.slots
+                        self._kadd(self._kind_demand, model_kind(t),
+                                   t.resources.slots)
+                        t.slot_ids = slots
+                        t.transition(TaskState.SCHEDULED, self.store)
+                        self._running[t.uid] = t
+                        self._dispatch(t)
+                    if sp is not None:
+                        sp.set_metadata(uid=" ".join(
+                            t.uid for t in pending[:cut]))
                 pending = [] if cut is None else pending[cut:]
             for t in pending:
                 self._enqueue(t, done_cb)
@@ -696,10 +703,11 @@ class Agent:
                 cb(free)
 
     def _schedule_pass(self):
-        with self._cv:
+        with self._cv, tracing.span("rp.agent.schedule") as sp:
             window = []
             rest = []
             launched = False
+            scheduled = [] if sp is not None else None
             while self._wait and len(window) < self.backfill_window:
                 window.append(heapq.heappop(self._wait))
             for item in window:
@@ -726,6 +734,10 @@ class Agent:
                 self._running[t.uid] = t
                 self._dispatch(t)
                 launched = True
+                if scheduled is not None:
+                    scheduled.append(t.uid)
+            if scheduled:
+                sp.set_metadata(uid=" ".join(scheduled))
             for item in rest:
                 heapq.heappush(self._wait, item)
             if launched and self._wait:
@@ -739,10 +751,15 @@ class Agent:
         in one pass run concurrently).  Caller holds self._cv; the
         transport takes only its own pool lock and never calls back into
         the agent from under it, so the ordering is acyclic."""
-        self.transport.dispatch(task)
+        with tracing.span("rp.transport.dispatch", task.uid):
+            self.transport.dispatch(task)
 
     # ---------------------------- execution ----------------------------- #
     def _run_task(self, task: TaskRecord):
+        with tracing.span("rp.agent.run", task.uid, slots=task.slot_ids):
+            self._run(task)
+
+    def _run(self, task: TaskRecord):
         task.transition(TaskState.LAUNCHING, self.store)
         if self.objectstore is not None:
             # deref ObjectRef inputs on the executing pilot: same-pilot
@@ -750,10 +767,11 @@ class Agent:
             # cross-pilot edges fetch once, cache, and count bytes_moved.
             # The overwrite is deliberate — a later retry re-ships values,
             # which is correct (the ref may be GC'd by then).
-            task.args = materialize(task.args, self.objectstore,
-                                    task.pilot_uid)
-            task.kwargs = materialize(task.kwargs, self.objectstore,
-                                      task.pilot_uid)
+            with tracing.span("rp.agent.materialize", task.uid):
+                task.args = materialize(task.args, self.objectstore,
+                                        task.pilot_uid)
+                task.kwargs = materialize(task.kwargs, self.objectstore,
+                                          task.pilot_uid)
         ctx = None
         if task.checkpointable:
             ctx = Checkpoint(self.ckpt, task.ckpt_key or task.uid)
@@ -785,19 +803,21 @@ class Agent:
                     with self._cv:
                         if self._ckpt_ctxs.get(task.uid) is ctx:
                             del self._ckpt_ctxs[task.uid]
-            if self.objectstore is not None:
-                # publish once: at/above the store threshold the result
-                # becomes an ObjectRef owned by this pilot; consumers
-                # deref lazily (docs/dataplane.md)
-                result = self.objectstore.maybe_publish(result,
-                                                        task.pilot_uid)
-            task.result = result
-            self._finish(task, TaskState.DONE, dt)
+            with tracing.span("rp.agent.finish", task.uid):
+                if self.objectstore is not None:
+                    # publish once: at/above the store threshold the
+                    # result becomes an ObjectRef owned by this pilot;
+                    # consumers deref lazily (docs/dataplane.md)
+                    result = self.objectstore.maybe_publish(
+                        result, task.pilot_uid)
+                task.result = result
+                self._finish(task, TaskState.DONE, dt)
         except TaskPreempted:
             self._preempt_finish(task)
         except BaseException as e:   # noqa: BLE001 — agent must survive
             task.error = e
-            self._finish(task, TaskState.FAILED, None)
+            with tracing.span("rp.agent.finish", task.uid):
+                self._finish(task, TaskState.FAILED, None)
 
     def _finish(self, task: TaskRecord, state: TaskState, duration):
         self.scheduler.release(task.uid)      # fires _on_capacity listener
@@ -1109,9 +1129,3 @@ class Agent:
                 self._replicas.pop(rep.uid, None)
                 self._replicated.discard(t.uid)
         return rep
-
-    # ------------------------------ stats ------------------------------- #
-    def utilization_timeline(self):
-        """Per-task state intervals for the Fig.6-style breakdown, derived
-        from the StateStore's unified event stream."""
-        return self.store.timeline()

@@ -48,6 +48,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import tracing
 from .executors import Executor, ParslTask, ThreadPoolExecutor
 from .futures import (AppFuture, ResourceSpec, RetryPolicy, TaskRecord,
                       TaskState, new_uid)
@@ -125,13 +126,10 @@ class DataFlowKernel:
                                     # exp11 placement baseline)
         self._lock = threading.Lock()
         self._invocation_idx: Dict[str, int] = {}
-        self.tasks: Dict[str, TaskRecord] = {}   # DAG nodes
-        self.edges: List[Tuple[str, str]] = []   # (producer, consumer)
         self.edge_bytes: List[Tuple[str, str, int]] = []
                                     # (producer uid, consumer uid, bytes)
                                     # per dataflow edge at launch time
         self.edge_bytes_total = 0
-        self.t_start = time.monotonic()
         # restart observability: keys that were interrupted last run and
         # carry a checkpoint — their tasks re-execute but resume from the
         # recorded step (the value) instead of step 0
@@ -186,6 +184,17 @@ class DataFlowKernel:
                executor: Optional[str] = None,
                sticky: Optional[bool] = None,
                retry_policy: Optional[RetryPolicy] = None) -> AppFuture:
+        with tracing.span("rp.dfk.submit") as sp:
+            future = self._submit(fn, args, kwargs, resources, retries,
+                                  executor, sticky, retry_policy)
+            if sp is not None:
+                # the pilot task's uid once handed to an executor, the DAG
+                # node's while it waits on its inputs
+                sp.set_metadata(uid=future.uid)
+        return future
+
+    def _submit(self, fn, args, kwargs, resources, retries, executor,
+                sticky, retry_policy) -> AppFuture:
         kwargs = kwargs or {}
         if sticky is not None:
             # per-invocation steal-eligibility override: threaded through the
@@ -206,7 +215,6 @@ class DataFlowKernel:
                           resources=resources or getattr(
                               fn, "__resources__", None) or ResourceSpec())
         future = AppFuture(node)
-        self.tasks[node.uid] = node
 
         # the executor-kind hint: explicit arg > app decorator > default
         label = (executor or getattr(fn, "__executor__", None)
@@ -240,7 +248,6 @@ class DataFlowKernel:
         inputs = _find_futures((args, kwargs))
         deps = [f for f in inputs if not f.done()]
         for d in deps:
-            self.edges.append((d.uid, node.uid))
             node.depends_on.append(d.uid)
 
         def launch() -> Optional[Tuple[str, ParslTask, AppFuture]]:
@@ -369,12 +376,19 @@ class DataFlowKernel:
                                 ready.append(n)
                 if not ready:
                     continue
-                items = [item for item in (n.launch() for n in ready)
-                         if item is not None]
-                if items:
-                    # dependency-ready batches are already coalesced —
-                    # submit them in this pass, not after a stream window
-                    self._dispatch_ready(items, immediate=True)
+                with tracing.span("rp.dfk.resolve") as sp:
+                    items = [item for item in (n.launch() for n in ready)
+                             if item is not None]
+                    if items:
+                        # dependency-ready batches are already coalesced —
+                        # submit them in this pass, not after a stream
+                        # window
+                        self._dispatch_ready(items, immediate=True)
+                    if sp is not None:
+                        sp.set_metadata(
+                            uid=" ".join(f.uid for _, _, f in items),
+                            cause=" ".join(f.uid for f in batch),
+                            ready=len(items))
         except BaseException:
             # never leave the drain flag wedged: a later completion must
             # be able to pick up whatever is still queued
@@ -476,7 +490,3 @@ class DataFlowKernel:
                 self._due.pop(label, None)
         for label, pairs in batches.items():
             self.executors[label].submit_bulk(pairs)
-
-    # ------------------------------ graph ------------------------------- #
-    def dag(self):
-        return {"nodes": list(self.tasks), "edges": list(self.edges)}

@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import tracing
 from .objectstore import ObjectRef
 
 
@@ -275,6 +276,7 @@ class TaskRecord:
             _validate_transition(self.state.value, state.value, self.uid)
         self.state = state
         self.timestamps[state.value] = time.monotonic()
+        tracing.mark("rp.state", self.uid, state.value)
         if store is not None:
             store.record(self)
 

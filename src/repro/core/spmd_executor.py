@@ -7,12 +7,18 @@ concurrently.  Here the persistent world is the pilot's device set; an
 functions are ``jax.lax`` ops under ``shard_map``.
 
 The paper's §V-A performance lesson — *build the communicator once, reuse
-it, cache it* — is structural here: sub-meshes and specialized callables are
-cached keyed by (function, sub-mesh, abstract inputs).  The first dispatch
-of a key pays trace+compile (the paper's `Launching`/`ibrun` analog); every
-subsequent task with the same signature is a cheap cached call.  The
-``cache=False`` mode exists only for the Exp-1 ablation that reproduces the
-paper's cold-communicator overhead.
+it, cache it* — is structural here: sub-meshes are cached by their devices
+and shape, and specialized callables (one ``jax.jit`` wrapper binding the
+function to its sub-mesh) by (function, sub-mesh).  The key holds no
+abstract inputs: a cached wrapper meets a new input shape by retracing and
+compiling inside ``jax.jit``'s own cache.  The ``cache=False`` mode exists
+only for the Exp-1 ablation that reproduces the paper's cold-communicator
+overhead.
+
+``stats``: ``specializations`` counts wrappers made, ``cache_hits`` wrappers
+reused, and ``compiles`` the XLA backend compiles and persistent-cache
+loads (``jax.monitoring``'s backend-compile event) that happened on a
+thread inside ``execute``: a new shape counts 1 or more, a repeat 0.
 
 On the CPU, slots may outnumber real devices: slot blocks then map onto
 the available devices (dedup'd), preserving scheduling semantics while
@@ -26,9 +32,33 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
 from ..sharding.partition import make_mesh
+from . import tracing
 from .futures import ResourceSpec, TaskRecord
+
+# the executor whose ``execute`` runs on this thread, for the compile count
+_executing = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_monitoring_event(event: str, duration: float, **_):
+    ex = getattr(_executing, "executor", None)
+    if ex is not None and event == BACKEND_COMPILE_EVENT:
+        with ex._lock:
+            ex.stats["compiles"] += 1
+
+
+def _listen_for_compiles():
+    """Register the one process-wide compile listener, once."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_monitoring_event)
+            _listening = True
 
 
 class SPMDFunctionExecutor:
@@ -38,7 +68,8 @@ class SPMDFunctionExecutor:
         self._mesh_cache: Dict[Tuple, Any] = {}
         self._call_cache: Dict[Tuple, Callable] = {}
         self._lock = threading.Lock()
-        self.stats = {"compiles": 0, "cache_hits": 0}
+        self.stats = {"compiles": 0, "specializations": 0, "cache_hits": 0}
+        _listen_for_compiles()
 
     # ----------------------------- sub-mesh ----------------------------- #
     def submesh(self, slot_ids: Tuple[int, ...],
@@ -90,7 +121,7 @@ class SPMDFunctionExecutor:
             if self.cache_enabled and key in self._call_cache:
                 self.stats["cache_hits"] += 1
                 return self._call_cache[key]
-            self.stats["compiles"] += 1
+            self.stats["specializations"] += 1
             if self.cache_enabled:
                 self._call_cache[key] = wrapped
         return wrapped
@@ -106,14 +137,25 @@ class SPMDFunctionExecutor:
             # skipped — step bodies manage their own jit.
             kwargs["ckpt"] = task.ckpt_ctx
             jit = False
-        if task.kind == "spmd":
-            mesh = self.submesh(task.slot_ids, task.resources.mesh_shape)
-            call = self._specialize(task.fn, mesh, jit)
-            out = call(*task.args, **kwargs)
-        else:  # plain python / bash-wrapped function: single slot
-            out = task.fn(*task.args, **kwargs)
-        out = jax.block_until_ready(out) if _has_arrays(out) else out
-        return out
+        outer = getattr(_executing, "executor", None)
+        _executing.executor = self
+        try:
+            with tracing.span("rp.executor.dispatch", task.uid,
+                              slots=task.slot_ids):
+                if task.kind == "spmd":
+                    mesh = self.submesh(task.slot_ids,
+                                        task.resources.mesh_shape)
+                    call = self._specialize(task.fn, mesh, jit)
+                    out = call(*task.args, **kwargs)
+                else:  # plain python / bash-wrapped function: single slot
+                    out = task.fn(*task.args, **kwargs)
+            if _has_arrays(out):
+                with tracing.span("rp.executor.device_wait", task.uid,
+                                  slots=task.slot_ids):
+                    out = jax.block_until_ready(out)
+            return out
+        finally:
+            _executing.executor = outer
 
 
 def _has_arrays(x) -> bool:

@@ -648,11 +648,6 @@ class ProcessTransport(_PoolBase):
             w.proc.terminate()
         w.proc.join(timeout=1.0)
 
-    @property
-    def n_procs(self) -> int:
-        with self._pcond:
-            return self._total
-
     def worker_pids(self, busy_only: bool = False) -> list:
         """Pids of live worker processes — the chaos harness's worker-kill
         and task-hang schedules pick their victims here (its presence is

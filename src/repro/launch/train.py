@@ -204,7 +204,10 @@ def main(argv=None):
         for at, f in evals:
             print(f"[train] eval at step {at}: loss {f.result():.4f}")
     loader.close()
-    print(f"[train] executor stats {rpex.pilot.executor.stats}")
+    stats = rpex.pilot.executor.stats
+    print(f"[train] executor: {stats['compiles']} compiles, "
+          f"{stats['specializations']} specializations, "
+          f"{stats['cache_hits']} cache hits")
     rpex.shutdown()
     if losses:
         print(f"[train] done: {step} steps, final loss {losses[-1]:.4f}, "

@@ -426,6 +426,7 @@ class AttnCache(NamedTuple):
     v: jnp.ndarray
 
 
+@jax.named_scope("attention")
 def attention_layer(cfg, w, x, *, local: bool, sctx, positions=None,
                     cache: Optional[AttnCache] = None, pos=None,
                     use_pallas: bool = False):

@@ -50,6 +50,7 @@ def apply_rope(x, positions, theta: float):
 
 # ------------------------------ MLP ----------------------------------- #
 
+@jax.named_scope("mlp")
 def mlp(x, w, gated: bool):
     """w: {'wi': (D,F), 'wg': (D,F) if gated, 'wo': (F,D)}."""
     h = jnp.einsum("...d,df->...f", x, w["wi"])

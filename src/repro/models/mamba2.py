@@ -111,11 +111,14 @@ def mamba_layer(cfg, w, x, *, sctx, cache: Optional[MambaCache] = None,
         xs, B_, C_ = jnp.split(xBC, [inner, inner + N], axis=-1)
         xh = xs.reshape(B, S, nh, P)
         xh = sctx.act(xh, ("batch", "seq", "ssm_heads", None))
-        if sctx.mesh is not None:
-            y, hT = sharded_ssd(sctx.mesh, xh, dt, A, B_, C_, cfg.ssm_chunk,
-                                use_pallas, rules=sctx.rules)
-        else:
-            y, hT = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk, use_pallas)
+        with jax.named_scope("ssd"):
+            if sctx.mesh is not None:
+                y, hT = sharded_ssd(sctx.mesh, xh, dt, A, B_, C_,
+                                    cfg.ssm_chunk, use_pallas,
+                                    rules=sctx.rules)
+            else:
+                y, hT = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk,
+                                    use_pallas)
         y = y + xh * w["D"].astype(y.dtype)[None, None, :, None]
         new_cache = MambaCache(hT.astype(jnp.float32), conv_tail)
     else:

@@ -21,6 +21,7 @@ from .layers import mlp, rms_norm, softcap
 
 # ----------------------------- embeddings ------------------------------ #
 
+@jax.named_scope("embed")
 def embed_inputs(cfg, params, batch, sctx: ShardCtx = NULL_CTX):
     """Token (+ stub-frontend) embedding.  Returns (B, S_total, D) embeds."""
     emb = params["embed"]
@@ -36,6 +37,7 @@ def embed_inputs(cfg, params, batch, sctx: ShardCtx = NULL_CTX):
     return sctx.act(x, ("batch", "seq", None))
 
 
+@jax.named_scope("loss")
 def lm_logits(cfg, params, hidden):
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", hidden, head.astype(hidden.dtype))
@@ -52,6 +54,7 @@ def _xent_block(cfg, params, hidden, targets, mask):
     return nll.sum(), mask.sum()
 
 
+@jax.named_scope("loss")
 def lm_loss(cfg, params, hidden, targets, mask, *, chunk: int = 2048):
     """Cross-entropy, chunked along sequence so (B, chunk, V) is the largest
     logits buffer ever live (a production necessity at V=256k)."""

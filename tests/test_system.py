@@ -34,19 +34,34 @@ def test_train_driver_end_to_end(tmp_path):
 
 def test_compile_cache_dir(monkeypatch):
     """An entry point keeps JAX's cache where JAX_COMPILATION_CACHE_DIR
-    says, and otherwise at the fixed <repo>/.jax_cache."""
+    says, and otherwise at the fixed <repo>/.jax_cache; either way the key
+    holds the programs' op names (named scopes) and no source locations,
+    so a program compiled from other code is not loaded in their place and
+    a checkout elsewhere loads the same programs."""
     from repro.launch.compile_cache import enable_compile_cache
     before = jax.config.jax_compilation_cache_dir
+    key_meta = jax.config.jax_compilation_cache_include_metadata_in_key
+    frames = jax.config.jax_traceback_in_locations_limit
     try:
         jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "cache-from-env")
         assert enable_compile_cache() == "cache-from-env"
         assert jax.config.jax_compilation_cache_dir is None   # left alone
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert enable_compile_cache() == str(REPO / ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert jax.config.jax_traceback_in_locations_limit == 0
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          key_meta)
+        jax.config.update("jax_traceback_in_locations_limit", frames)
 
 
 def test_serve_driver_end_to_end():
