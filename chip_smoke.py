@@ -3,8 +3,9 @@
     python chip_smoke.py               # one chip
     python chip_smoke.py --four-chips  # the multi-chip path, on four chips
 
-One chip: both Pallas kernels compiled at real widths and compared with the
-float32 references of ``repro.kernels.ref``, then SmolLM-360M at its full
+One chip: the Pallas kernels compiled at real widths and compared with the
+float32 references of ``repro.kernels.ref`` (the models' fused attention
+kernel forward and backward), then SmolLM-360M at its full
 published width trained through the workflow path (DFK -> RPEXExecutor ->
 pilot -> agent -> SPMDFunctionExecutor -> jitted step) in two segments with
 a checkpoint and an eval, then resumed from that checkpoint for one more
@@ -26,6 +27,7 @@ import shutil
 import sys
 import threading
 import time
+from functools import partial
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -68,7 +70,7 @@ def kernels_phase():
         return fn(*args)
 
     smol = get_config("smollm-360m")
-    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    ks = jax.random.split(jax.random.PRNGKey(0), 9)
     (B, S), D = FLASH_BATCH_SEQ, smol.head_dim
     q = jax.random.normal(ks[0], (B, S, smol.num_heads, D), jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, S, smol.num_kv_heads, D), jnp.bfloat16)
@@ -81,6 +83,25 @@ def kernels_phase():
     say(f"flash_attention q{tuple(q.shape)} kv{tuple(k.shape)} bf16: "
         f"max abs err {err:.3e} (tolerance {FLASH_TOL})")
     check(err <= FLASH_TOL, "flash_attention disagrees with the reference")
+
+    do = jax.random.normal(ks[8], q.shape, jnp.bfloat16)
+
+    def fwd_bwd(attn, *a):
+        out, vjp = jax.vjp(attn, *a[:3])
+        return (out,) + vjp(a[3])
+
+    got = compiled_kernel(jax.jit(partial(fwd_bwd, ops.splash_attention)),
+                          q, k, v, do)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(partial(fwd_bwd, ref.attention_reference))(
+            f32(q), f32(k), f32(v), f32(do))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        err = max_err(g, w)
+        say(f"splash_attention {name}: max abs err {err:.3e} (tolerance "
+            f"{FLASH_TOL} x max|ref| = {FLASH_TOL * scale:.3e})")
+        check(err <= FLASH_TOL * scale,
+              f"splash_attention {name} disagrees with the reference")
 
     mamba = get_config("mamba2-1.3b")
     H, P, N, Q = (mamba.ssm_heads, mamba.ssm_head_dim, mamba.ssm_state,

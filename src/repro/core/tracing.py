@@ -18,10 +18,17 @@ lists of uids or slots are joined with spaces.
 The benchmark's per-layer metrics read them (``bench/program_trace.py``).
 Spans inside a spawned ``proc`` transport worker run in another process
 and do not reach the trace.
+
+``count(name)`` adds one to a process-wide counter, for events of the
+program's trace time rather than its run time (which path a layer took
+when a step was traced); ``counts(prefix)`` reads them.  Like the spans,
+they need no setting.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import threading
 from typing import Optional
 
 from jax._src.lib import _profiler
@@ -29,6 +36,8 @@ from jax.profiler import TraceAnnotation
 
 _on = _profiler.TraceMe.is_enabled
 _OFF = contextlib.nullcontext()
+_counts: collections.Counter = collections.Counter()
+_counts_lock = threading.Lock()
 
 
 def span(name: str, uid: Optional[str] = None, *, slots=None):
@@ -51,3 +60,16 @@ def mark(name: str, uid: str, state: str):
     if _on():
         with TraceAnnotation(name, uid=uid, state=state):
             pass
+
+
+def count(name: str) -> None:
+    """Add one to the counter ``name``."""
+    with _counts_lock:
+        _counts[name] += 1
+
+
+def counts(prefix: str = "") -> dict:
+    """The counters whose names start with ``prefix``, as they stand."""
+    with _counts_lock:
+        return {k: v for k, v in sorted(_counts.items())
+                if k.startswith(prefix)}

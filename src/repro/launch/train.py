@@ -33,7 +33,7 @@ import numpy as np
 from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get_config, reduce_config
 from repro.core import (DataFlowKernel, PilotDescription, RPEXExecutor,
-                        SlotScheduler, python_app, spmd_app)
+                        SlotScheduler, python_app, spmd_app, tracing)
 from repro.data.pipeline import DataConfig, ShardedLoader
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
@@ -207,7 +207,8 @@ def main(argv=None):
     stats = rpex.pilot.executor.stats
     print(f"[train] executor: {stats['compiles']} compiles, "
           f"{stats['specializations']} specializations, "
-          f"{stats['cache_hits']} cache hits")
+          f"{stats['cache_hits']} cache hits; attention paths traced "
+          f"{tracing.counts('attention.')}")
     rpex.shutdown()
     if losses:
         print(f"[train] done: {step} steps, final loss {losses[-1]:.4f}, "

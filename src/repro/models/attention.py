@@ -1,10 +1,18 @@
-"""GQA attention: blockwise flash-style (train/prefill) + KV-cache decode.
+"""GQA attention: flash-style (train/prefill) + KV-cache decode.
+
+``flash_attention`` is the one entry for attention over a whole sequence:
+on a TPU, with q at the static offset 0 and a causal mask, it runs the
+fused Pallas kernel (``repro.kernels.ops.splash_attention``); otherwise
+``blockwise_attention``.  ``attention_path`` makes that choice from what
+the call can observe, and ``repro.core.tracing`` counts it at trace time
+(``attention.kernel``, ``attention.jnp.<reason>``).
 
 The blockwise implementation is the pure-JAX statement of the flash
 algorithm (online softmax over KV blocks via ``lax.scan``): it is the
-compile-anywhere path used by the dry-run, and the oracle the Pallas TPU
-kernel in ``repro.kernels`` is validated against.  Memory is O(S * block_k)
-instead of O(S^2), which is what makes the 32k-prefill cells lowerable.
+compile-anywhere path used by the dry-run and on the CPU, the path of
+sequence-parallel q chunks, and an oracle for the kernels.  Memory is
+O(S * block_k) instead of O(S^2), which is what makes the 32k-prefill
+cells lowerable.
 """
 from __future__ import annotations
 
@@ -16,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
+
+from repro.core import tracing
 
 from .layers import apply_rope, softcap
 
@@ -215,6 +225,40 @@ def _flash_bwd(causal, window, cap, block_k, block_q, res, dout):
 blockwise_attention.defvjp(_flash_fwd_vjp, _flash_bwd)
 
 
+def attention_path(q_offset, causal: bool = True) -> tuple:
+    """("kernel", "") where the fused kernel runs the call, else ("jnp",
+    reason): "backend" off a TPU, "q_offset" where q does not start at the
+    static position 0 (sequence-parallel chunks), "mask" where the mask is
+    not causal (padding to the kernel's blocks relies on causality).  The
+    kernel takes every sliding window and soft cap."""
+    from repro.kernels import ops as kops  # Pallas takes ~1.6 s to import
+    if not kops.on_tpu():
+        return "jnp", "backend"
+    if not (isinstance(q_offset, int) and q_offset == 0):
+        return "jnp", "q_offset"
+    if not causal:
+        return "jnp", "mask"
+    return "kernel", ""
+
+
+def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
+                    window: int = 0, attn_softcap: float = 0.0):
+    """Attention of q (B,Sq,Hq,D) over k/v (B,Skv,Hkv,D): the fused kernel
+    or ``blockwise_attention``, as ``attention_path`` decides, counted.
+    ``q_offset`` is the Python int 0 or a traced position of q[:, 0]."""
+    path, reason = attention_path(q_offset, causal)
+    tracing.count(f"attention.{path}.{reason}" if reason
+                  else f"attention.{path}")
+    if path == "kernel":
+        from repro.kernels import ops as kops
+        return kops.splash_attention(q, k, v, window=window,
+                                     attn_softcap=attn_softcap)
+    if isinstance(q_offset, int):
+        q_offset = jnp.full((), q_offset, jnp.int32)
+    return blockwise_attention(q, k, v, q_offset, causal, window,
+                               attn_softcap)
+
+
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
                      attn_softcap: float = 0.0):
     """Single-token attention against a cache.
@@ -242,6 +286,19 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
 
 # ---------------------- sharded attention wrappers ---------------------- #
 
+def tp_strategy(S: int, Hq: int, Hkv: int, M: int) -> str:
+    """``sharded_flash_attention``'s strategy for a model axis of M."""
+    if M <= 1:
+        return "local"
+    if Hkv % M == 0:
+        return "kv_heads"
+    if Hq % M == 0 and (Hq // Hkv) % (Hq // M) == 0:
+        return "q_heads"
+    if S % M == 0:
+        return "seq"
+    return "local"
+
+
 def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
                             attn_softcap: float = 0.0, rules=None):
     """shard_map'd flash attention; picks the TP strategy per shape.
@@ -267,27 +324,16 @@ def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
     b_axes = (tuple(bspec) if isinstance(bspec, tuple)
               else ((bspec,) if bspec else ()))
     M = 1 if "model" in b_axes else mesh.shape.get("model", 1)
-    zero = jnp.zeros((), jnp.int32)
+    strategy = tp_strategy(S, Hq, Hkv, M)
 
-    if M <= 1:
-        strategy = "local"
-    elif Hkv % M == 0:
-        strategy = "kv_heads"
-    elif Hq % M == 0 and G % (Hq // M) == 0:
-        strategy = "q_heads"
-    elif S % M == 0:
-        strategy = "seq"
-    else:
-        strategy = "local"
-
+    attend = partial(flash_attention, window=window, attn_softcap=attn_softcap)
     if strategy == "local" and bspec is None:
-        return blockwise_attention(q, k, v, zero, True, window, attn_softcap)
+        return attend(q, k, v)
 
     if strategy in ("local", "kv_heads"):
         hspec = "model" if strategy == "kv_heads" else None
         fn = shard_map(
-            lambda q_, k_, v_: blockwise_attention(
-                q_, k_, v_, zero, True, window, attn_softcap),
+            attend,
             mesh=mesh,
             in_specs=(P(bspec, None, hspec, None),) * 3,
             out_specs=P(bspec, None, hspec, None), check_vma=False)
@@ -301,8 +347,7 @@ def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
             kv_idx = (m * Hq_l) // G       # the single kv head this shard uses
             k1 = jax.lax.dynamic_slice_in_dim(k_, kv_idx, 1, axis=2)
             v1 = jax.lax.dynamic_slice_in_dim(v_, kv_idx, 1, axis=2)
-            return blockwise_attention(q_, k1, v1, zero, True, window,
-                                       attn_softcap)
+            return attend(q_, k1, v1)
 
         fn = shard_map(
             local, mesh=mesh,
@@ -315,9 +360,7 @@ def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
     S_l = S // M
 
     def local(q_, k_, v_):
-        off = jax.lax.axis_index("model") * S_l
-        return blockwise_attention(q_, k_, v_, off, True, window,
-                                   attn_softcap)
+        return attend(q_, k_, v_, jax.lax.axis_index("model") * S_l)
 
     fn = shard_map(
         local, mesh=mesh,
@@ -432,8 +475,8 @@ def attention_layer(cfg, w, x, *, local: bool, sctx, positions=None,
                     use_pallas: bool = False):
     """Pre-norm attention mixer.  Returns (out, new_cache).
 
-    Train/prefill: cache is None -> blockwise flash over x itself, and (for
-    prefill) the produced K/V are returned as the new cache.
+    Train/prefill: cache is None -> ``flash_attention`` over x itself, and
+    (for prefill) the produced K/V are returned as the new cache.
     Decode: cache given, x is (B, 1, D), ``pos`` scalar write index.
     """
     window = cfg.sliding_window if local else 0
@@ -458,8 +501,8 @@ def attention_layer(cfg, w, x, *, local: bool, sctx, positions=None,
                                           attn_softcap=cfg.attn_softcap,
                                           rules=sctx.rules)
         else:
-            out = blockwise_attention(q, kx, vx, jnp.zeros((), jnp.int32),
-                                      True, window, cfg.attn_softcap)
+            out = flash_attention(q, kx, vx, window=window,
+                                  attn_softcap=cfg.attn_softcap)
         new_cache = AttnCache(kx, vx)
     else:
         if sctx.mesh is not None:

@@ -39,6 +39,29 @@ def test_flash_kernel_sweep(B, Sq, Hq, Hkv, D, dtype):
                                    atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,window,cap", [
+    (2, 128, 6, 2, 64, 0, 0.0),       # grouped heads, causal
+    (1, 200, 4, 2, 64, 37, 20.0),     # window + soft cap; S padded to 256
+])
+def test_splash_attention_matches_reference(B, S, Hq, Hkv, D, window, cap):
+    """The fused kernel (interpret mode here): output and dq/dk/dv."""
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, Hq, D))
+    k = jax.random.normal(ks[1], (B, S, Hkv, D))
+    v = jax.random.normal(ks[2], (B, S, Hkv, D))
+    do = jax.random.normal(ks[3], (B, S, Hq, D))
+    results = []
+    for attn in (lambda q, k, v: ops.splash_attention(
+                     q, k, v, window=window, attn_softcap=cap),
+                 lambda q, k, v: attention_reference(
+                     q, k, v, causal=True, window=window, attn_softcap=cap)):
+        out, vjp = jax.vjp(attn, q, k, v)
+        results.append((out,) + vjp(do))
+    for name, got, want in zip(("out", "dq", "dk", "dv"), *results):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5, err_msg=name)
+
+
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [
     (1, 32, 2, 8, 4, 8),
     (2, 64, 4, 16, 8, 16),
