@@ -8,8 +8,14 @@ a data file found by that name (``bench/configs/<config>.json``,
 of its check are in ``bench/workloads/<cell>.json``; a per-layer metric is
 read by ``bench/metrics/<metric>.py``.  The traffic file names the pattern
 that drives it, ``bench/patterns/<pattern>.py`` (see ``bench/generator.py``).
-Nothing here needs an edit when a cell, a configuration, a traffic mix, a
-pattern or a metric is added.
+The configuration file names its float32 reference model,
+``bench/reference/<reference>.py``, which also counts the architecture's
+FLOPs (``bench.reference.model``, ``bench/flops.py``); its tiny CPU
+stand-in is ``bench/tests/tiny_data/configs/<config>.json`` (see
+``bench/tests/tiny.py``).  Nothing here needs an edit when a cell, a
+configuration (a new architecture with its reference among them), a traffic
+mix, a pattern or a metric is added: each is new files and entries in
+``BENCHMARK.json``.
 
 A run: look for the chips the cell asks for (none found: exit 2, no result);
 set up (weights from the seed, the cell's shapes warmed, the first steps or

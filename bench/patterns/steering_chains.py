@@ -208,13 +208,14 @@ class Pattern:
         import jax
         import jax.numpy as jnp
 
-        from bench.reference.train import MODELS
+        from bench import reference
         from repro.models import transformer as T
         conf, p = self.cell.config, self.p
         w = weights.flat(weights.maker(T.abstract_params(self.cfg))(
             weights.seed_key(self.seed)))
         w = {k: v.astype(jnp.float32) for k, v in w.items()}
-        model, cfg_ref = MODELS[conf["reference"]], G.ref_model(conf)
+        model = reference.model(conf["reference"])
+        cfg_ref = G.ref_model(conf)
 
         @jax.jit
         def losses(w, tok, tgt):

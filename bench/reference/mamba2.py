@@ -89,3 +89,15 @@ def token_nll(weights, tokens, targets, cfg, prec="f32"):
     logits = mm("bsd,vd->bsv", h, weights["embed"], prec)
     gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return jax.nn.logsumexp(logits, axis=-1) - gold
+
+
+def flops_per_token(s: dict, seq: int) -> float:
+    """Forward FLOPs a token at context ``seq`` (``bench/flops.py``)."""
+    d, L, E, N = (s["hidden_size"], s["num_hidden_layers"], s["d_inner"],
+                  s["state_size"])
+    P, Q, W = s["head_dim"], s["chunk_size"], s["conv_kernel"]
+    H = E // P
+    proj = d * (2 * E + 2 * N + H) + E * d + W * (E + 2 * N)
+    q = min(Q, seq)
+    ssd = N * (q + 1) / 2 + H * P * (q + 1) / 2 + 2 * H * P * N
+    return 2.0 * (L * (proj + ssd) + d * s["vocab_size"])

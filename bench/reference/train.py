@@ -1,12 +1,15 @@
 """The reference's first training steps, and the numbers a check compares.
 
-``follow`` runs a plain reference model (``transformer`` or ``mamba2``)
-through AdamW from the same initial weights and rows as the program, and
-returns, for steps 1..n: each step's loss, the per-leaf norms of the first
-gradient as the optimizer gets it (after clipping), and the per-leaf norms of
-the parameters' change after step n.  A leaf is one layer's slice of a
-stacked array, or a whole unstacked array.  Gradients are summed over blocks
-of ``rows`` sequences so that the float32 logits and activations fit.
+``follow`` runs a plain reference model through AdamW from the same initial
+weights and rows as the program, and returns, for steps 1..n: each step's
+loss, the per-leaf norms of the first gradient as the optimizer gets it
+(after clipping), and the per-leaf norms of the parameters' change after
+step n.  The model is named as the configuration file names it
+(``"reference"``) and found by that name (``bench.reference.model``), so a
+new architecture's reference needs no edit here.  A leaf is one layer's
+slice of a stacked array, or a whole unstacked array.  Gradients are summed
+over blocks of ``rows`` sequences so that the float32 logits and
+activations fit.
 """
 from __future__ import annotations
 
@@ -16,9 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import adamw, mamba2, transformer
-
-MODELS = {"transformer": transformer, "mamba2": mamba2}
+from . import adamw
+from . import model as reference_model
 
 
 def norm_rows(name: str, v):
@@ -50,7 +52,8 @@ def _block_grad(weights, tokens, targets, *, model, cfg_items, prec):
     cfg = dict(cfg_items)
 
     def total_nll(w):
-        return MODELS[model].token_nll(w, tokens, targets, cfg, prec).sum()
+        return reference_model(model).token_nll(w, tokens, targets, cfg,
+                                                prec).sum()
 
     with jax.default_matmul_precision("highest"):
         return jax.value_and_grad(total_nll)(weights)

@@ -89,3 +89,13 @@ def token_nll(weights, tokens, targets, cfg, prec="f32"):
     logits = mm("bsd,vd->bsv", h, weights["embed"], prec)
     gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return jax.nn.logsumexp(logits, axis=-1) - gold
+
+
+def flops_per_token(s: dict, seq: int) -> float:
+    """Forward FLOPs a token at context ``seq`` (``bench/flops.py``)."""
+    d, L = s["hidden_size"], s["num_hidden_layers"]
+    H, Hkv, hd = (s["num_attention_heads"], s["num_key_value_heads"],
+                  s["head_dim"])
+    per_layer = d * H * hd * 2 + d * Hkv * hd * 2 + 3 * d * s["intermediate_size"]
+    attn = 2 * H * hd * (seq + 1) / 2          # q.k and p.v, causal half
+    return 2.0 * (L * (per_layer + attn) + d * s["vocab_size"])

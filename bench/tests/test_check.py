@@ -1,11 +1,12 @@
 """The check that decides ``correct``, driven through the harness.
 
-At the tiny sizes of ``tiny.py`` on the CPU: the look for a chip is skipped
-and the rest of a run goes as on the chip, with the cell's own limits.  A
-sound run comes out correct; each fault the cell's pattern can have,
-planted in the timed path, and the fp8 control, put in the program's
-place, come out not correct (one chip: no exchange between chips to leave
-out); the control reads well above the program.
+Every cell of ``BENCHMARK.json``, at the tiny sizes of ``tiny.py`` on the
+CPU: the look for a chip is skipped and the rest of a run goes as on the
+chip, with the cell's own limits.  A sound run comes out correct; each
+fault the cell's pattern can have, planted in the timed path, and the fp8
+control, put in the program's place, come out not correct (one chip: no
+exchange between chips to leave out); the control reads well above the
+program.
 """
 import subprocess
 import sys
@@ -16,7 +17,8 @@ from bench import generator, harness
 from bench.tests import tiny
 
 ARGS = ["--seed", "2718281828459045", "--seconds", "2", "--trace", "0"]
-CELLS = ["train.smollm-360m", "fanout.smollm-360m", "fanout.mamba2-1.3b"]
+CELLS = [w["name"] for w in
+         harness.load_json(harness.ROOT / "BENCHMARK.json")["workloads"]]
 
 
 def _run(name, fault=None, report=None, seed=None):

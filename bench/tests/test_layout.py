@@ -1,17 +1,20 @@
 """BENCHMARK.json against the files the harness finds by name.
 
-Every configuration, traffic mix, cell and per-layer metric is a file of its
-own; this test lists the directories and holds them to BENCHMARK.json and to
-the benchmark's rules, so that adding a cell is adding files.
+Every configuration (with its reference model and tiny stand-in), traffic
+mix, cell and per-layer metric is a file of its own; this test lists the
+directories and holds them to BENCHMARK.json and to the benchmark's rules,
+so that adding a cell or an architecture is adding files.
 """
 import json
 import re
 import shutil
+import sys
 from pathlib import Path
 
 import pytest
 
-from bench import generator, harness
+from bench import flops, generator, harness, reference
+from bench.tests import tiny
 
 ROOT = harness.ROOT
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -164,3 +167,89 @@ def test_adding_a_mix_is_adding_files(tmp_path, monkeypatch):
     cell = harness.load_cell(new["name"])
     assert cell.traffic["in_flight"] == 64
     assert generator.load_pattern(cell.traffic["pattern"]).Pattern
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_every_configuration_resolves(name):
+    """Its reference model and FLOPs count are found by the name its file
+    gives, and it has a tiny stand-in for the CPU tests."""
+    conf = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                      .read_text())
+    model = reference.model(conf["reference"])
+    assert callable(model.token_nll) and callable(model.flops_per_token)
+    assert flops.per_token(conf, 1024, training=False) > 0
+    assert (ROOT / "bench" / "tests" / "tiny_data" / "configs"
+            / f"{name}.json").is_file()
+    assert tiny.config(name)["name"] == name
+
+
+@pytest.mark.parametrize("name", ["no_such_model", "numerics", "train",
+                                  "adamw", "corpus", "__init__"])
+def test_unknown_reference_is_refused(name):
+    """A name that is no reference model, a shared helper among them, is
+    refused with the known models listed."""
+    with pytest.raises(SystemExit, match="transformer"):
+        reference.model(name)
+
+
+TINY_ARCH = '''"""A new architecture's float32 reference (here: the dense decoder's)."""
+from .transformer import flops_per_token, token_nll  # noqa: F401
+'''
+
+
+def test_adding_a_configuration_is_adding_files(tmp_path, monkeypatch):
+    """A configuration of a new architecture is its reference module, its
+    configuration file, its tiny stand-in, a workload file (and its tiny
+    limits) and BENCHMARK.json entries, with no edit of a file that was
+    there; its tiny cell runs correct through the harness."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "data"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    old = next(w for w in bench["workloads"] if w["traffic"] == "fanout"
+               and w["config"] == "smollm-360m")
+    conf = json.loads((ROOT / "bench" / "configs" / "smollm-360m.json")
+                      .read_text())
+    new = {
+        "reference/tiny_arch.py": TINY_ARCH,
+        "configs/tiny-arch.json": json.dumps(dict(
+            conf, name="tiny-arch", reference="tiny_arch")),
+        "workloads/fanout.tiny-arch.json": json.dumps(dict(
+            json.loads((ROOT / "bench" / "workloads" / f"{old['name']}.json")
+                       .read_text()), config="tiny-arch")),
+        "tests/tiny_data/configs/tiny-arch.json": (
+            ROOT / "bench/tests/tiny_data/configs/smollm-360m.json"
+        ).read_text(),
+        "tests/tiny_data/workloads/fanout.tiny-arch.json": (
+            ROOT / "bench/tests/tiny_data/workloads/fanout.smollm-360m.json"
+        ).read_text()}
+    for rel, text in new.items():
+        path = tmp_path / "bench" / rel
+        assert not path.exists(), rel
+        path.write_text(text)
+    bench["configs"].append(dict(bench["configs"][0], name="tiny-arch",
+                                 file="bench/configs/tiny-arch.json"))
+    bench["workloads"].append(dict(old, name="fanout.tiny-arch",
+                                   config="tiny-arch"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(harness, "ROOT", tmp_path)
+    monkeypatch.setattr(harness, "BENCH", tmp_path / "bench")
+    try:
+        cell = tiny.cell("fanout.tiny-arch")
+        model = reference.model(cell.config["reference"])
+        assert model.__file__ == str(tmp_path / "bench" / "reference"
+                                     / "tiny_arch.py")
+        base = json.loads((ROOT / "bench" / "configs" / "smollm-360m.json")
+                          .read_text())
+        assert flops.per_token(cell.config, 1024, training=True) == \
+            flops.per_token(dict(base, sizes=cell.config["sizes"]), 1024,
+                            training=True)
+        res = harness.run(["--workload", "fanout.tiny-arch", "--seed", "31",
+                           "--seconds", "2"], allow_cpu=True, cell=cell)
+    finally:
+        sys.modules.pop("bench.reference.tiny_arch", None)
+    assert res["correct"] is True, res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    for path, data in before.items():
+        assert path.read_bytes() == data, path

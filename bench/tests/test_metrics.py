@@ -105,6 +105,14 @@ def test_flops_per_token_smollm_by_hand():
     assert flops.per_token(conf, S, training=True) == pytest.approx(want)
 
 
+def test_flops_per_token_mamba2_as_before():
+    """The parent's counts at Mamba2's configuration and 1024 tokens, from
+    before the count moved beside the reference model."""
+    conf = harness.load_cell("fanout.mamba2-1.3b").config
+    assert flops.per_token(conf, 1024, training=False) == 2839255040.0
+    assert flops.per_token(conf, 1024, training=True) == 8517765120.0
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(KeyError):
         tr.peaks_for("TPU v9 imaginary")

@@ -1,8 +1,9 @@
 """The float32 references against the program, at small sizes on the CPU.
 
-Both sides compute in float32 here (the program's ``dtype`` set to
-float32), so they agree to float32 rounding: the reference is the same
-model, written independently.
+Every configuration of ``BENCHMARK.json``, at its tiny stand-in.  Both
+sides compute in float32 here (the program's ``dtype`` set to float32), so
+they agree to float32 rounding: the reference is the same model, written
+independently.
 """
 import dataclasses
 
@@ -11,15 +12,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import generator, weights
+from bench import generator, harness, weights
 from bench.reference import corpus, train as ref_train
 from bench.tests import tiny
 
 jax.config.update("jax_default_matmul_precision", "highest")
+CONFIGS = [c["name"] for c in
+           harness.load_json(harness.ROOT / "BENCHMARK.json")["configs"]]
 
 
 def _setup(name):
-    from bench import harness
     conf = tiny.config(name)
     cfg = dataclasses.replace(harness.program_config(conf), dtype="float32",
                               remat="none")
@@ -29,7 +31,7 @@ def _setup(name):
     return conf, cfg, params, tok, tgt
 
 
-@pytest.mark.parametrize("name", ["smollm-360m", "mamba2-1.3b"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_loss_and_grads_match_program(name):
     from repro.models import model as M
     conf, cfg, params, tok, tgt = _setup(name)
