@@ -1,0 +1,9 @@
+"""experts_device_ms.fanout: device time of the operations whose innermost
+scope is ``experts`` (the held experts' grouped matmuls and their gate) in
+the traced window, per score task completed in it
+(``bench/expert_scopes.py``)."""
+from bench import expert_scopes
+
+
+def read(ctx):
+    return expert_scopes.ms_per_score(ctx, "experts")

@@ -17,6 +17,7 @@ ARCHS = [
     "internvl2-76b",
     "musicgen-large",
     "mamba2-1.3b",
+    "granite-4.0-h-small",
 ]
 
 _MODULE = {
@@ -30,6 +31,7 @@ _MODULE = {
     "internvl2-76b": "internvl2_76b",
     "musicgen-large": "musicgen_large",
     "mamba2-1.3b": "mamba2_1_3b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 

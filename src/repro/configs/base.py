@@ -31,7 +31,13 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_every: int = 1               # MoE FFN every k-th layer (jamba: 2)
     capacity_factor: float = 1.25
-    moe_dispatch: str = "einsum"     # einsum (GShard-style) | gather (zero-FLOP)
+    # einsum (GShard-style) | gather (zero-FLOP) | ragged (dropless, grouped
+    # matmuls over the rows routed to each held expert)
+    moe_dispatch: str = "einsum"
+    moe_held: int = 0                # experts this chip holds (0: all of them),
+    moe_first: int = 0               # a contiguous block from this one
+    shared_expert_ff: int = 0        # width of an always-on shared expert
+    moe_aux_coef: float = 0.01       # load-balancing loss in the train loss
 
     # --- attention variants ---
     sliding_window: int = 0          # window size for local layers (gemma2: 4096)
@@ -39,6 +45,8 @@ class ModelConfig:
     attn_softcap: float = 0.0        # gemma2: 50.0
     logit_softcap: float = 0.0       # gemma2: 30.0
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"  # rope | nope (no positional encoding)
+    attn_scale: float = 0.0          # score scale (0: head_dim ** -0.5)
 
     # --- SSM / hybrid ---
     ssm_state: int = 0               # N (mamba2 d_state)
@@ -47,6 +55,9 @@ class ModelConfig:
     ssm_chunk: int = 256             # SSD chunk length
     conv_width: int = 4
     attn_every: int = 0              # hybrid: 1 attention layer per `attn_every` (jamba: 8)
+    attn_offset: int = -1            # its index in that period (-1: the last)
+    mamba_conv_bias: bool = False
+    mamba_gated_norm: bool = False   # RMSNorm(y * SiLU(z)) before out_proj
 
     # --- frontend stubs ---
     frontend: str = "none"           # none | vision_stub | audio_stub
@@ -54,6 +65,9 @@ class ModelConfig:
 
     # --- misc ---
     tie_embeddings: bool = False
+    embedding_multiplier: float = 1.0   # embeddings x this (granite: 12)
+    residual_multiplier: float = 1.0    # each residual branch x this
+    logits_scaling: float = 1.0         # logits / this
     gated_mlp: bool = True
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
@@ -71,12 +85,24 @@ class ModelConfig:
     def inner_dim(self) -> int:
         return self.d_inner or 2 * self.d_model
 
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held or self.num_experts
+
+    @property
+    def layer_types(self) -> list:
+        """The mixer of each layer, by the Hugging Face configs' names."""
+        names = {"attn": "attention", "local_attn": "local_attention",
+                 "mamba": "mamba"}
+        return [names[self.layer_kind(i)] for i in range(self.num_layers)]
+
     def layer_kind(self, i: int) -> str:
         """Mixer kind for layer i: 'attn' | 'local_attn' | 'mamba'."""
         if self.family == "ssm":
             return "mamba"
-        if self.attn_every:  # hybrid (jamba): one attn per attn_every layers
-            return "attn" if (i % self.attn_every) == (self.attn_every - 1) else "mamba"
+        if self.attn_every:  # hybrid: one attn per attn_every layers
+            at = self.attn_offset if self.attn_offset >= 0 else self.attn_every - 1
+            return "attn" if i % self.attn_every == at else "mamba"
         if self.local_global_alternate:
             return "local_attn" if i % 2 == 0 else "attn"
         return "attn"
@@ -108,13 +134,16 @@ class ModelConfig:
                 n += inner * d                          # out_proj
                 n += self.conv_width * (inner + 2 * N)  # conv
                 n += 3 * nh                             # A_log, D, dt_bias
+                n += (inner + 2 * N) * self.mamba_conv_bias
+                n += inner * self.mamba_gated_norm
             fk = self.ffn_kind(i)
             mats = 3 if self.gated_mlp else 2
             if fk == "dense":
                 n += mats * d * self.d_ff
             elif fk == "moe":
                 n += d * self.num_experts               # router
-                n += self.num_experts * mats * d * self.d_ff
+                n += self.experts_held * mats * d * self.d_ff
+                n += mats * d * self.shared_expert_ff
             n += d + (d if fk != "none" else 0)         # pre-mixer (+pre-ffn) norms
         n += d                                          # final norm
         if self.frontend == "vision_stub":
@@ -129,7 +158,8 @@ class ModelConfig:
         mats = 3 if self.gated_mlp else 2
         n = self.param_count()
         n_moe_layers = sum(1 for i in range(self.num_layers) if self.ffn_kind(i) == "moe")
-        inactive = n_moe_layers * (self.num_experts - self.num_experts_per_tok) * mats * d * self.d_ff
+        idle = max(self.experts_held - self.num_experts_per_tok, 0)
+        inactive = n_moe_layers * idle * mats * d * self.d_ff
         return n - inactive
 
     def model_flops_per_token(self, training: bool) -> float:
@@ -159,7 +189,8 @@ SHAPES = {
 
 # Archs with a sub-quadratic mechanism run long_500k; pure full-attention
 # archs skip it (recorded in DESIGN.md §Arch-applicability).
-LONG_CONTEXT_OK = {"gemma2-9b", "jamba-1.5-large-398b", "mamba2-1.3b"}
+LONG_CONTEXT_OK = {"gemma2-9b", "jamba-1.5-large-398b", "mamba2-1.3b",
+                   "granite-4.0-h-small"}
 
 
 def cells(arch: str) -> list:
@@ -182,9 +213,12 @@ def reduce_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads else 2,
         head_dim=16,
         d_ff=128 if cfg.d_ff else 0,
+        shared_expert_ff=128 if cfg.shared_expert_ff else 0,
         vocab_size=257,
         num_experts=4 if cfg.num_experts else 0,
         num_experts_per_tok=2 if cfg.num_experts else 0,
+        moe_held=0,
+        moe_first=0,
         d_inner=128 if (cfg.family in ("ssm", "hybrid")) else 0,
         ssm_state=16 if cfg.ssm_state else 0,
         ssm_head_dim=32,

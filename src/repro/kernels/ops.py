@@ -69,15 +69,17 @@ def _splash_kernel(S: int, Hq: int, window: int, attn_softcap: float,
 
 
 def splash_attention(q, k, v, *, window: int = 0, attn_softcap: float = 0.0,
+                     scale: Optional[float] = None,
                      interpret: Optional[bool] = None):
     """Causal GQA flash attention, fused: q (B,S,Hq,D) x k/v (B,S,Hkv,D).
 
     Same contract as ``repro.kernels.ref.attention_reference`` with
     ``causal=True``; ``window`` keeps keys with q - window < kv <= q.  S is
     padded up to the block multiple: under the causal mask padded keys come
-    after every real query, and padded query rows are dropped.  The scale
-    1/sqrt(D) is applied to q (exact for a power-of-two D).  Softmax
-    statistics and accumulators are f32; p meets V in V's dtype.
+    after every real query, and padded query rows are dropped.  The score
+    scale, ``scale`` or else 1/sqrt(D), is applied to q (exact where it is
+    a power of two).  Softmax statistics and accumulators are f32; p meets
+    V in V's dtype.
     """
     B, S, Hq, D = q.shape
     S_pad, _ = _splash_blocks(S)
@@ -89,7 +91,7 @@ def splash_attention(q, k, v, *, window: int = 0, attn_softcap: float = 0.0,
             x = jnp.pad(x, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
         return x.transpose(0, 2, 1, 3)
 
-    q = q * jnp.asarray(D ** -0.5, q.dtype)
+    q = q * jnp.asarray(D ** -0.5 if scale is None else scale, q.dtype)
     out = jax.vmap(kernel)(heads_major(q), heads_major(k), heads_major(v))
     return out.transpose(0, 2, 1, 3)[:, :S]
 

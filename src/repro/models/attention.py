@@ -71,10 +71,11 @@ def _blockify(k, block_k):
     return k.reshape(B, nblk, block_k, Hkv, D), nblk, pad
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def blockwise_attention(q, k, v, q_offset, causal: bool = True,
                         window: int = 0, attn_softcap: float = 0.0,
-                        block_k: int = 512, block_q: int = 512):
+                        block_k: int = 512, block_q: int = 512,
+                        scale: Optional[float] = None):
     """Flash attention in pure JAX: q (B,Sq,Hq,D) x k/v (B,Skv,Hkv,D).
 
     Double-blocked (q x kv nested scans): transients are O(block_q*block_k),
@@ -84,10 +85,11 @@ def blockwise_attention(q, k, v, q_offset, causal: bool = True,
 
     ``q_offset`` (int32 scalar array, traced) is the absolute position of
     q[:, 0] — nonzero under sequence-parallel attention where each model
-    shard owns a contiguous q chunk.
+    shard owns a contiguous q chunk.  Scores are q.k x ``scale`` (None:
+    D ** -0.5).
     """
     out, _ = _flash_fwd(q, k, v, q_offset, causal, window, attn_softcap,
-                        block_k, block_q)
+                        block_k, block_q, scale)
     return out
 
 
@@ -100,11 +102,12 @@ def _qblockify(q, block_q):
     return q.reshape(B, nq, block_q, Hkv, G, D), nq, pad
 
 
-def _flash_fwd(q, k, v, q_offset, causal, window, cap, block_k, block_q):
+def _flash_fwd(q, k, v, q_offset, causal, window, cap, block_k, block_q,
+               scale):
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     qb, nq, qpad = _qblockify(q.reshape(B, Sq, Hkv, G, D), block_q)
     kb, nk, _ = _blockify(k, block_k)
     vb, _, _ = _blockify(v, block_k)
@@ -147,18 +150,19 @@ def _flash_fwd(q, k, v, q_offset, causal, window, cap, block_k, block_q):
     return out, (q, k, v, q_offset, out, lse)
 
 
-def _flash_fwd_vjp(q, k, v, q_offset, causal, window, cap, block_k, block_q):
+def _flash_fwd_vjp(q, k, v, q_offset, causal, window, cap, block_k, block_q,
+                   scale):
     out, res = _flash_fwd(q, k, v, q_offset, causal, window, cap, block_k,
-                          block_q)
+                          block_q, scale)
     return out, res
 
 
-def _flash_bwd(causal, window, cap, block_k, block_q, res, dout):
+def _flash_bwd(causal, window, cap, block_k, block_q, scale, res, dout):
     q, k, v, q_offset, out, lse = res
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     og = (out.astype(jnp.float32) * dout.astype(jnp.float32)) \
         .reshape(B, Sq, Hkv, G, D).sum(axis=-1)            # delta (B,Sq,Hkv,G)
     qb, nq, qpad = _qblockify(q.reshape(B, Sq, Hkv, G, D), block_q)
@@ -242,25 +246,28 @@ def attention_path(q_offset, causal: bool = True) -> tuple:
 
 
 def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
-                    window: int = 0, attn_softcap: float = 0.0):
+                    window: int = 0, attn_softcap: float = 0.0,
+                    scale: Optional[float] = None):
     """Attention of q (B,Sq,Hq,D) over k/v (B,Skv,Hkv,D): the fused kernel
     or ``blockwise_attention``, as ``attention_path`` decides, counted.
-    ``q_offset`` is the Python int 0 or a traced position of q[:, 0]."""
+    ``q_offset`` is the Python int 0 or a traced position of q[:, 0];
+    scores are q.k x ``scale`` (None: D ** -0.5)."""
     path, reason = attention_path(q_offset, causal)
     tracing.count(f"attention.{path}.{reason}" if reason
                   else f"attention.{path}")
     if path == "kernel":
         from repro.kernels import ops as kops
         return kops.splash_attention(q, k, v, window=window,
-                                     attn_softcap=attn_softcap)
+                                     attn_softcap=attn_softcap, scale=scale)
     if isinstance(q_offset, int):
         q_offset = jnp.full((), q_offset, jnp.int32)
     return blockwise_attention(q, k, v, q_offset, causal, window,
-                               attn_softcap)
+                               attn_softcap, 512, 512, scale)
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
-                     attn_softcap: float = 0.0):
+                     attn_softcap: float = 0.0,
+                     scale: Optional[float] = None):
     """Single-token attention against a cache.
 
     q: (B, 1, Hq, D); caches: (B, S, Hkv, D); pos: scalar index of the new
@@ -271,7 +278,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     G = Hq // Hkv
     qg = q.reshape(B, Hkv, G, D)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k_cache,
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
+                   preferred_element_type=jnp.float32) * (
+                       D ** -0.5 if scale is None else scale)
     if attn_softcap:
         s = softcap(s, attn_softcap)
     kv_pos = jnp.arange(S)
@@ -300,7 +308,8 @@ def tp_strategy(S: int, Hq: int, Hkv: int, M: int) -> str:
 
 
 def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
-                            attn_softcap: float = 0.0, rules=None):
+                            attn_softcap: float = 0.0, rules=None,
+                            scale: Optional[float] = None):
     """shard_map'd flash attention; picks the TP strategy per shape.
 
     Strategy (with M = size of the model axis, when not already consumed by
@@ -326,7 +335,8 @@ def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
     M = 1 if "model" in b_axes else mesh.shape.get("model", 1)
     strategy = tp_strategy(S, Hq, Hkv, M)
 
-    attend = partial(flash_attention, window=window, attn_softcap=attn_softcap)
+    attend = partial(flash_attention, window=window, attn_softcap=attn_softcap,
+                     scale=scale)
     if strategy == "local" and bspec is None:
         return attend(q, k, v)
 
@@ -372,7 +382,7 @@ def sharded_flash_attention(mesh, q, k, v, *, window: int = 0,
 
 def sharded_decode_attention(mesh, q, k_cache, v_cache, kx, vx, pos, *,
                              window: int = 0, attn_softcap: float = 0.0,
-                             rules=None):
+                             rules=None, scale: Optional[float] = None):
     """shard_map'd single-token decode: writes (kx, vx) at ``pos`` then
     attends.  The strategy is DERIVED from the partition rules' resolution
     of the cache's logical axes ("batch","seq_kv","kv_heads","head_dim") —
@@ -393,7 +403,7 @@ def sharded_decode_attention(mesh, q, k_cache, v_cache, kx, vx, pos, *,
                                 k_cache.shape, mesh))
     spec = spec + (None,) * (4 - len(spec))
     bspec, seqspec, hspec, dspec = spec
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     seq_axes = (tuple(seqspec) if isinstance(seqspec, tuple)
                 else ((seqspec,) if seqspec else ()))
@@ -480,42 +490,49 @@ def attention_layer(cfg, w, x, *, local: bool, sctx, positions=None,
     Decode: cache given, x is (B, 1, D), ``pos`` scalar write index.
     """
     window = cfg.sliding_window if local else 0
+    scale = cfg.attn_scale or None
     B, S, _ = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, w["wq"])
     kx = jnp.einsum("bsd,dhk->bshk", x, w["wk"])
     vx = jnp.einsum("bsd,dhk->bshk", x, w["wv"])
-    if positions is None:
-        positions = (jnp.arange(S) if pos is None else (pos + jnp.zeros((S,), jnp.int32)))
-        positions = jnp.broadcast_to(positions, (B, S))
-    q = apply_rope(q, positions, cfg.rope_theta)
-    kx = apply_rope(kx, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        if positions is None:
+            positions = (jnp.arange(S) if pos is None
+                         else (pos + jnp.zeros((S,), jnp.int32)))
+            positions = jnp.broadcast_to(positions, (B, S))
+        q = apply_rope(q, positions, cfg.rope_theta)
+        kx = apply_rope(kx, positions, cfg.rope_theta)
     q = sctx.act(q, ("batch", "seq", "heads", "head_dim"))
 
     if cache is None:
         if use_pallas:
+            if scale is not None:
+                raise ValueError("the forward-only Pallas kernel "
+                                 "(use_pallas) scales scores by D ** -0.5 "
+                                 "only; attn_scale needs the default path")
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, kx, vx, causal=True, window=window,
                                        attn_softcap=cfg.attn_softcap)
         elif sctx.mesh is not None:
             out = sharded_flash_attention(sctx.mesh, q, kx, vx, window=window,
                                           attn_softcap=cfg.attn_softcap,
-                                          rules=sctx.rules)
+                                          rules=sctx.rules, scale=scale)
         else:
             out = flash_attention(q, kx, vx, window=window,
-                                  attn_softcap=cfg.attn_softcap)
+                                  attn_softcap=cfg.attn_softcap, scale=scale)
         new_cache = AttnCache(kx, vx)
     else:
         if sctx.mesh is not None:
             out, kc, vc = sharded_decode_attention(
                 sctx.mesh, q, cache.k, cache.v, kx, vx, pos, window=window,
-                attn_softcap=cfg.attn_softcap, rules=sctx.rules)
+                attn_softcap=cfg.attn_softcap, rules=sctx.rules, scale=scale)
         else:
             kc = jax.lax.dynamic_update_slice_in_dim(
                 cache.k, kx.astype(cache.k.dtype), pos, axis=1)
             vc = jax.lax.dynamic_update_slice_in_dim(
                 cache.v, vx.astype(cache.v.dtype), pos, axis=1)
             out = decode_attention(q, kc, vc, pos, window=window,
-                                   attn_softcap=cfg.attn_softcap)
+                                   attn_softcap=cfg.attn_softcap, scale=scale)
         new_cache = AttnCache(kc, vc)
     out = jnp.einsum("bshk,hkd->bsd", out, w["wo"])
     return sctx.act(out, ("batch", "seq", None)), new_cache

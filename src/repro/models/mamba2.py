@@ -9,6 +9,11 @@ intra-chunk term.
 
 Decode keeps the SSM state (B,H,P,N) + a rolling conv window; each step is
 O(1) in context length — this is what makes the 500k-context cells runnable.
+
+Two parts of the published block are options of the config, off by default:
+a bias on the depthwise convolution (``mamba_conv_bias``) and the gated
+RMSNorm, norm(y * SiLU(z)) in float32 with its scale stored as an offset
+from 1, in place of y * SiLU(z) before ``out_proj`` (``mamba_gated_norm``).
 """
 from __future__ import annotations
 
@@ -18,11 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 
+from .layers import rms_norm
+
 
 def mamba_params_spec(cfg):
     d, inner, nh, N = cfg.d_model, cfg.inner_dim, cfg.ssm_heads, cfg.ssm_state
     cw = cfg.conv_width
-    return {
+    spec = {
         "in_proj": ((d, 2 * inner + 2 * N + nh), ("embed_w", "ssm_inner")),
         "out_proj": ((inner, d), ("ssm_inner", "embed_w")),
         "conv_w": ((cw, inner + 2 * N), (None, "ssm_inner")),
@@ -30,6 +37,11 @@ def mamba_params_spec(cfg):
         "D": ((nh,), ("ssm_heads",)),
         "dt_bias": ((nh,), ("ssm_heads",)),
     }
+    if cfg.mamba_conv_bias:
+        spec["conv_b"] = ((inner + 2 * N,), ("ssm_inner",))
+    if cfg.mamba_gated_norm:
+        spec["norm"] = ((inner,), ("ssm_inner",))
+    return spec
 
 
 class MambaCache(NamedTuple):
@@ -43,14 +55,17 @@ def _split_proj(cfg, zxbcdt):
     return z, xBC, dt
 
 
-def _causal_conv(xBC, conv_w, prev: Optional[jnp.ndarray] = None):
-    """Depthwise causal conv1d. xBC: (B, S, C); conv_w: (W, C)."""
+def _causal_conv(xBC, conv_w, prev: Optional[jnp.ndarray] = None,
+                 bias: Optional[jnp.ndarray] = None):
+    """Depthwise causal conv1d. xBC: (B, S, C); conv_w: (W, C); bias: (C,)."""
     W = conv_w.shape[0]
     if prev is None:
         prev = jnp.zeros((xBC.shape[0], W - 1, xBC.shape[2]), xBC.dtype)
     xp = jnp.concatenate([prev, xBC], axis=1)
     out = sum(xp[:, i:i + xBC.shape[1], :] * conv_w[i][None, None, :]
               for i in range(W))
+    if bias is not None:
+        out = out + bias
     return jax.nn.silu(out), xp[:, -(W - 1):, :]
 
 
@@ -105,9 +120,10 @@ def mamba_layer(cfg, w, x, *, sctx, cache: Optional[MambaCache] = None,
     z, xBC, dt = _split_proj(cfg, zxbcdt)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + w["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(w["A_log"].astype(jnp.float32))             # (H,)
+    conv_b = w["conv_b"] if cfg.mamba_conv_bias else None
 
     if cache is None:
-        xBC, conv_tail = _causal_conv(xBC, w["conv_w"])
+        xBC, conv_tail = _causal_conv(xBC, w["conv_w"], bias=conv_b)
         xs, B_, C_ = jnp.split(xBC, [inner, inner + N], axis=-1)
         xh = xs.reshape(B, S, nh, P)
         xh = sctx.act(xh, ("batch", "seq", "ssm_heads", None))
@@ -123,7 +139,8 @@ def mamba_layer(cfg, w, x, *, sctx, cache: Optional[MambaCache] = None,
         new_cache = MambaCache(hT.astype(jnp.float32), conv_tail)
     else:
         # single-token recurrence: h <- exp(dt*A) h + dt * (B outer x)
-        xBC, conv_tail = _causal_conv(xBC, w["conv_w"], prev=cache.conv)
+        xBC, conv_tail = _causal_conv(xBC, w["conv_w"], prev=cache.conv,
+                                      bias=conv_b)
         xs, B_, C_ = jnp.split(xBC, [inner, inner + N], axis=-1)
         xh = xs.reshape(B, 1, nh, P)[:, 0]                    # (B, H, P)
         dt1 = dt[:, 0]                                        # (B, H)
@@ -137,6 +154,10 @@ def mamba_layer(cfg, w, x, *, sctx, cache: Optional[MambaCache] = None,
         new_cache = MambaCache(h, conv_tail)
 
     y = y.reshape(B, S, inner)
-    y = y * jax.nn.silu(z)
+    if cfg.mamba_gated_norm:
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(g, w["norm"], cfg.norm_eps).astype(x.dtype)
+    else:
+        y = y * jax.nn.silu(z)
     out = jnp.einsum("bse,ed->bsd", y, w["out_proj"])
     return sctx.act(out, ("batch", "seq", None)), new_cache

@@ -27,6 +27,8 @@ def embed_inputs(cfg, params, batch, sctx: ShardCtx = NULL_CTX):
     emb = params["embed"]
     tok = batch["tokens"]
     x = jnp.take(emb, tok, axis=0).astype(jnp.dtype(cfg.dtype))
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.frontend == "vision_stub" and "patches" in batch:
         p = batch["patches"].astype(x.dtype)
         w = params["connector"]
@@ -41,6 +43,8 @@ def embed_inputs(cfg, params, batch, sctx: ShardCtx = NULL_CTX):
 def lm_logits(cfg, params, hidden):
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", hidden, head.astype(hidden.dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return softcap(logits, cfg.logit_softcap)
 
 
@@ -89,8 +93,8 @@ def loss_fn(cfg, params, batch, sctx: ShardCtx = NULL_CTX, use_pallas=False):
         nfe = batch["patches"].shape[1]
         hidden = hidden[:, nfe:]
     loss = lm_loss(cfg, params, hidden, targets, mask)
-    if cfg.num_experts:
-        loss = loss + 0.01 * aux
+    if cfg.num_experts and cfg.moe_aux_coef:
+        loss = loss + cfg.moe_aux_coef * aux
     return loss, {"loss": loss, "aux": aux}
 
 

@@ -1,31 +1,47 @@
-"""Capacity-based top-k Mixture-of-Experts (GShard/Switch lineage).
+"""Top-k Mixture-of-Experts: capacity-based (GShard/Switch lineage) or
+dropless.
 
-Two dispatch modes (select via ``cfg.moe_dispatch`` — a §Perf hillclimb knob):
+Three dispatch modes (``cfg.moe_dispatch``):
 
-* ``einsum``  — classic TPU one-hot dispatch/combine einsums. Baseline;
-  matches what TPU MoE systems of the paper's era actually ran.  Its one-hot
-  matmuls are counted (and executed) as real MXU FLOPs.
-* ``gather``  — zero-FLOP dispatch: token->slot indices built with a cumsum +
-  scatter, tokens moved by gather, combined by gather.  Removes the dispatch
-  einsum FLOPs entirely (beyond-paper optimization).
+* ``einsum``  — classic TPU one-hot dispatch/combine einsums at a capacity.
+  Baseline; matches what TPU MoE systems of the paper's era actually ran.
+  Its one-hot matmuls are counted (and executed) as real MXU FLOPs.
+* ``gather``  — zero-FLOP dispatch at the same capacity: token->slot indices
+  built with a cumsum + scatter, tokens moved by gather, combined by gather.
+* ``ragged``  — dropless: every (token, expert) assignment to an expert the
+  layer holds is computed.  The assignments are sorted by expert, their
+  rows gathered and multiplied by ``jax.lax.ragged_dot``, one group of rows
+  per expert, so the expert FLOPs follow the rows routed here.
 
-Tokens are processed in groups so the dispatch tensors stay VMEM-sized.
-Experts are sharded on the ``model`` mesh axis (EP); token groups on
-``data`` — the cross product is the all-to-all the XLA partitioner inserts.
+The capacity modes process tokens in groups so the dispatch tensors stay
+VMEM-sized; experts are sharded on the ``model`` mesh axis (EP), token
+groups on ``data`` — the cross product is the all-to-all the XLA
+partitioner inserts.
+
+A ``ragged`` layer may hold a contiguous block of the experts
+(``cfg.moe_held`` of them from ``cfg.moe_first``), one chip's share under
+expert parallelism: it routes over all ``num_experts`` and gives its own
+experts' part of the result, leaving the absent experts' part to the chips
+that hold them.  The layer is one trace-time count of
+``moe.dispatch.ragged`` (``repro.core.tracing``) and the scopes ``moe``
+(router, top-k, sort, gathers, combine) and ``experts`` inside it (the
+grouped matmuls).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
+
 
 def moe_params_spec(cfg):
     # "expert_ff" is unsharded by default (FSDP handles the d dim); the
     # decode-optimized rule set maps it to the data axis (2D expert-TP, so
     # weights are never all-gathered at serving time).
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.experts_held
     spec = {
-        "router": ((d, e), ("embed_w", None)),
+        "router": ((d, cfg.num_experts), ("embed_w", None)),
         "wi": ((e, d, f), ("expert", "expert_embed", "expert_ff")),
         "wo": ((e, f, d), ("expert", "expert_ff", "expert_embed")),
     }
@@ -73,8 +89,48 @@ def _expert_ffn(xe, w, gated):
     return jnp.einsum("gecf,efd->gecd", h, w["wo"])
 
 
+@jax.named_scope("moe")
+def dropless_moe(x, w, cfg):
+    """x: (B, S, D) -> (the held experts' part of the output, aux_loss)."""
+    tracing.count("moe.dispatch.ragged")
+    B, S, D = x.shape
+    T, K, held = B * S, cfg.num_experts_per_tok, cfg.experts_held
+    xt = x.reshape(T, D)
+    gates, idx, aux = _route(xt[None], w["router"], cfg)     # (1, T, K)
+    local = idx[0] - cfg.moe_first
+    mine = (local >= 0) & (local < held)                     # (T, K)
+    key = jnp.where(mine, local, held).reshape(T * K)        # held: not here
+    order = jnp.argsort(key, stable=True)
+    # a token takes an expert at most once, so at most min(K, held) of its
+    # assignments are here: the first M rows by expert hold all of them
+    M = T * min(K, held)
+    rows = jnp.take(xt, order[:M] // K, axis=0)              # (M, D)
+    sizes = jnp.bincount(key, length=held + 1)[:held]
+    with jax.named_scope("experts"):
+        h = jax.lax.ragged_dot(rows, w["wi"], sizes)
+        if cfg.gated_mlp:
+            h = jax.nn.silu(jax.lax.ragged_dot(rows, w["wg"], sizes)) * h
+        else:
+            h = jax.nn.gelu(h)
+        y = jax.lax.ragged_dot(h, w["wo"], sizes)            # (M, D)
+    # each assignment's row of y; those not here read the zero row M
+    rank = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.arange(T * K, dtype=jnp.int32))
+    at = jnp.where(mine.reshape(T * K), rank, M)
+    y = jnp.concatenate([y, jnp.zeros((1, D), y.dtype)])
+    yk = jnp.take(y, at, axis=0).reshape(T, K, D)
+    out = jnp.einsum("tkd,tk->td", yk, (gates[0] * mine).astype(y.dtype))
+    return out.reshape(B, S, D), aux
+
+
 def moe_ffn(x, w, cfg, sctx, group_size: int = 4096):
     """x: (B, S, D) -> (B, S, D).  Returns (out, aux_loss)."""
+    if cfg.moe_dispatch == "ragged":
+        out, aux = dropless_moe(x, w, cfg)
+        return sctx.act(out, ("batch", "seq", None)), aux
+    if cfg.experts_held != cfg.num_experts:
+        raise ValueError("a layer that holds a share of the experts needs "
+                         "moe_dispatch='ragged'")
     B, S, D = x.shape
     T = B * S
     g = max(1, T // min(group_size, T))
@@ -110,10 +166,12 @@ def moe_ffn(x, w, cfg, sctx, group_size: int = 4096):
     else:  # gather dispatch: zero-FLOP data movement
         tok = jnp.broadcast_to(jnp.arange(Tg)[None, :, None], idx.shape)
         slot_src = jnp.full((g, E, C), Tg, jnp.int32)        # Tg = "no token"
+        # a dropped assignment writes nowhere: its expert index E is out of
+        # bounds, so it cannot race a kept token for a slot
         slot_src = slot_src.at[
             jnp.arange(g)[:, None, None],
-            jnp.where(keep, idx, E - 1),
-            jnp.where(keep, pos, C - 1)].set(jnp.where(keep, tok, Tg))
+            jnp.where(keep, idx, E),
+            jnp.where(keep, pos, 0)].set(tok, mode="drop")
         xpad = jnp.concatenate([xg, jnp.zeros((g, 1, D), xg.dtype)], axis=1)
         xe = jnp.take_along_axis(
             xpad, slot_src.reshape(g, E * C)[..., None],

@@ -64,8 +64,8 @@ def program_period(cfg) -> int:
 
 # ----------------------------- param specs ------------------------------ #
 
-def _dense_ffn_spec(cfg):
-    d, f = cfg.d_model, cfg.d_ff
+def _dense_ffn_spec(cfg, f=None):
+    d, f = cfg.d_model, f or cfg.d_ff
     s = {"wi": ((d, f), ("embed_w", "mlp")), "wo": ((f, d), ("mlp", "embed_w"))}
     if cfg.gated_mlp:
         s["wg"] = ((d, f), ("embed_w", "mlp"))
@@ -82,6 +82,8 @@ def sublayer_spec(cfg, mixer: str, ffn: str):
     if ffn != "none":
         spec["norm2"] = ((d,), ("embed_w",))
         spec["ffn"] = moe_params_spec(cfg) if ffn == "moe" else _dense_ffn_spec(cfg)
+        if ffn == "moe" and cfg.shared_expert_ff:
+            spec["shared"] = _dense_ffn_spec(cfg, cfg.shared_expert_ff)
     return spec
 
 
@@ -146,7 +148,7 @@ def init_params(cfg, key, dtype=None):
     def mk(leaf, k):
         shape, axes = leaf
         core = axes[1:] if axes[:1] == ("layers",) else axes
-        if core == ("embed_w",):                    # norm scale, stored as delta
+        if core in (("embed_w",), ("ssm_inner",)):  # norm scale (a delta), bias
             return jnp.zeros(shape, dtype)
         if core == ("ssm_heads",):                  # A_log / dt_bias / D
             return jax.random.uniform(k, shape, jnp.float32, 0.5, 1.5)
@@ -221,16 +223,19 @@ def _apply_sublayer(cfg, kind, ffn, w, x, *, sctx, positions, cache, pos,
     else:
         mix, new_cache = mamba_layer(cfg, w["mixer"], h, sctx=sctx,
                                      cache=cache, use_pallas=use_pallas)
-    x = x + mix
+    r = cfg.residual_multiplier
+    x = x + (mix * r if r != 1.0 else mix)
     aux = jnp.zeros((), jnp.float32)
     if ffn != "none":
         h = rms_norm(x, w["norm2"], cfg.norm_eps)
         if ffn == "moe":
             out, aux = moe_ffn(h, w["ffn"], cfg, sctx)
+            if cfg.shared_expert_ff:
+                out = out + mlp(h, w["shared"], cfg.gated_mlp)
         else:
             out = mlp(h, w["ffn"], cfg.gated_mlp)
             out = sctx.act(out, ("batch", "seq", None))
-        x = x + out
+        x = x + (out * r if r != 1.0 else out)
     return x, new_cache, aux
 
 

@@ -90,8 +90,11 @@ def test_param_count_matches_init(arch):
 
 
 def test_cells_cover_40():
+    """Four dry-run shapes an arch: 40 for the ten assigned archs and 4 for
+    granite-4.0-h-small, whose long-context cell runs (a hybrid, like
+    jamba); the seven pure full-attention archs skip theirs."""
     total = sum(len(cells(a)) for a in ARCHS)
-    assert total == 40
+    assert total == 4 * len(ARCHS) == 44
     runs = sum(1 for a in ARCHS for _, s in cells(a) if s == "RUN")
     skips = total - runs
-    assert runs == 33 and skips == 7
+    assert runs == 37 and skips == 7

@@ -141,3 +141,31 @@ def test_smollm_train_step_with_kernel_fits_one_v5e(one_chip, monkeypatch):
     assert (jnp_kernels, kernels >= 3) == (0, True), (jnp_kernels, kernels)
     assert kernel_path < V5E_HBM_BYTES, kernel_path
     assert kernel_path <= jnp_path, (kernel_path, jnp_path)
+
+
+def test_granite_score_program_fits_one_v5e(one_chip, monkeypatch):
+    """The fan-out's score program for granite-4.0-h-small's one-chip share
+    (one 10-layer period, 9 of the 72 experts held, every width as
+    published) at 4 x 1024 tokens on the fused attention kernel: the NoPE
+    layer's kernel and the grouped expert matmuls are there, and weights
+    and temporaries fit the chip."""
+    import dataclasses
+
+    from repro.models import transformer as T
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("granite-4.0-h-small"),
+                              num_layers=10, moe_held=9)
+    params = _on(one_chip, T.abstract_params(cfg))
+    tok = _on(one_chip, jax.ShapeDtypeStruct((4, 1024), jnp.int32))
+
+    def score(params, tokens):
+        x = M.embed_inputs(cfg, params, {"tokens": tokens})
+        h, _, _ = T.forward(cfg, params, x, mode="train")
+        return M.lm_logits(cfg, params, h).astype(jnp.float32).mean()
+
+    compiled = jax.jit(score).lower(params, tok).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert "splash" in text and "ragged-dot" in text
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES / 2
